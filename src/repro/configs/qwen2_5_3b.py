@@ -1,4 +1,4 @@
-"""qwen2.5-3b [dense] — GQA, QKV bias. [hf:Qwen/Qwen2.5-0.5B; hf]"""
+"""qwen2.5-3b [dense] — GQA, QKV bias. [hf:Qwen/Qwen2.5-3B config.json]"""
 from repro.configs.base import ArchConfig
 
 CONFIG = ArchConfig(
@@ -15,5 +15,5 @@ CONFIG = ArchConfig(
     rope_theta=1_000_000.0,
     tie_embeddings=True,
     sub_quadratic=False,
-    source="hf:Qwen/Qwen2.5-0.5B; hf",
+    source="hf:Qwen/Qwen2.5-3B config.json",
 )

@@ -8,7 +8,8 @@ g-fold — the paper's "avoid layout-conversion copies at boundaries" lesson
 applied to head layout.
 
 Kernels:
-  _flash_fwd   : grid (B, Hq, nQ, nK) -> out, lse
+  _flash_fwd   : grid (B, Hq, nQ, nK) -> out, lse (lse carried as a
+                 (…, Sq, 1) column so its block obeys Mosaic's tiling rule)
   _flash_dq    : grid (B, Hq, nQ, nK) -> dq
   _flash_dkv   : grid (B, Hkv, nK, g*nQ) -> dk, dv  (inner axis walks the
                  g q-heads of the group × their q blocks; scratch persists)
@@ -101,7 +102,7 @@ def _flash_fwd_kernel(
         l = l_ref[...]
         l_safe = jnp.where(l == 0, 1.0, l)
         o_ref[0, 0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_ref[...] + jnp.log(l_safe))[:, 0]
+        lse_ref[0, 0] = m_ref[...] + jnp.log(l_safe)
 
 
 def _pad_seq(x, block, axis):
@@ -156,11 +157,11 @@ def flash_attention_pallas(
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda b_, h, i, j: (b_, h, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b_, h, i, j: (b_, h, i)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b_, h, i, j: (b_, h, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(qt.shape, q.dtype),
-            jax.ShapeDtypeStruct(qt.shape[:3], jnp.float32),
+            jax.ShapeDtypeStruct(qt.shape[:3] + (1,), jnp.float32),
         ],
         scratch_shapes=[
             plc.VMEM((bq, d), jnp.float32),
@@ -174,7 +175,7 @@ def flash_attention_pallas(
         name="repro_flash_fwd",
     )(qt, kt, vt)
     out = out[:, :, :sq].transpose(0, 2, 1, 3)
-    return out, lse[:, :, :sq]
+    return out, lse[:, :, :sq, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -203,8 +204,8 @@ def _flash_dq_kernel(
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0][:, None]                  # (bq,1)
-        dd = dd_ref[0, 0][:, None]
+        lse = lse_ref[0, 0]                           # (bq,1)
+        dd = dd_ref[0, 0]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         s = _mask(s, iq, ik, bq, bk, causal=causal, window=window, sk=sk)
         p = jnp.exp(s - lse)
@@ -242,8 +243,8 @@ def _flash_dkv_kernel(
         k = k_ref[0, 0].astype(jnp.float32)
         v = v_ref[0, 0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0][:, None]
-        dd = dd_ref[0, 0][:, None]
+        lse = lse_ref[0, 0]
+        dd = dd_ref[0, 0]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
         s = _mask(s, iq, ik, bq, bk, causal=causal, window=window, sk=sk)
         # mask padded q rows too (their lse is garbage)
@@ -283,8 +284,11 @@ def flash_attention_bwd_pallas(
     vt = _pad_seq(v.transpose(0, 2, 1, 3), bk, 2)
     dot = _pad_seq(do.transpose(0, 2, 1, 3), bq, 2)
     ot = _pad_seq(out.transpose(0, 2, 1, 3), bq, 2)
-    dd = jnp.sum(dot.astype(jnp.float32) * ot.astype(jnp.float32), axis=-1)
-    lse_p = _pad_seq(lse, bq, 2)
+    # per-row scalars travel as (…, Sq', 1) columns: a (bq, 1) block is the
+    # layout Mosaic accepts for a row vector ((8, 128)-or-full-dim rule)
+    dd = jnp.sum(dot.astype(jnp.float32) * ot.astype(jnp.float32), axis=-1,
+                 keepdims=True)
+    lse_p = _pad_seq(lse[..., None], bq, 2)
     n_q, n_k = qt.shape[2] // bq, kt.shape[2] // bk
     # --- dq ---
     grid = (b, hq, n_q, n_k)
@@ -300,8 +304,8 @@ def flash_attention_bwd_pallas(
             pl.BlockSpec((1, 1, bk, d), lambda b_, h, i, j, g=g: (b_, h // g, j, 0)),
             pl.BlockSpec((1, 1, bk, d), lambda b_, h, i, j, g=g: (b_, h // g, j, 0)),
             pl.BlockSpec((1, 1, bq, d), lambda b_, h, i, j: (b_, h, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b_, h, i, j: (b_, h, i)),
-            pl.BlockSpec((1, 1, bq), lambda b_, h, i, j: (b_, h, i)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b_, h, i, j: (b_, h, i, 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b_, h, i, j: (b_, h, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, d), lambda b_, h, i, j: (b_, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
@@ -319,9 +323,6 @@ def flash_attention_bwd_pallas(
     def qix(b_, h, jk, inner, g=g, n_q=n_q):
         return (b_, h * g + inner // n_q, inner % n_q, 0)
 
-    def qix3(b_, h, jk, inner, g=g, n_q=n_q):
-        return (b_, h * g + inner // n_q, inner % n_q)
-
     dk, dv = pl.pallas_call(
         functools.partial(
             _flash_dkv_kernel,
@@ -334,8 +335,8 @@ def flash_attention_bwd_pallas(
             pl.BlockSpec((1, 1, bk, d), lambda b_, h, jk, inner: (b_, h, jk, 0)),
             pl.BlockSpec((1, 1, bk, d), lambda b_, h, jk, inner: (b_, h, jk, 0)),
             pl.BlockSpec((1, 1, bq, d), qix),
-            pl.BlockSpec((1, 1, bq), qix3),
-            pl.BlockSpec((1, 1, bq), qix3),
+            pl.BlockSpec((1, 1, bq, 1), qix),
+            pl.BlockSpec((1, 1, bq, 1), qix),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk, d), lambda b_, h, jk, inner: (b_, h, jk, 0)),

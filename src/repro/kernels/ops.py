@@ -694,17 +694,17 @@ _ssd_p.defvjp(_ssd_p_fwd, _ssd_p_bwd)
 def ssd_scan(
     x, dt, A, B_, C, *, chunk: int = 64, initial_state=None, return_state=False
 ):
-    """Mamba-2 SSD. B_/C: (B,S,G,N). Pallas path requires G==1."""
+    """Mamba-2 SSD. B_/C: (B,S,G,N). The Pallas path raises unless G==1."""
     if return_state or initial_state is not None:
         # stateful path (serving): no grad needed; direct dispatch
-        if _pallas() and B_.shape[2] == 1:
+        if _pallas():
             return ssd_scan_pallas(
                 x, dt, A, B_, C, chunk=chunk, initial_state=initial_state
             )
         return ref.ssd_scan(
             x, dt, A, B_, C, chunk=chunk, initial_state=initial_state
         )
-    if _pallas() and B_.shape[2] == 1:
+    if _pallas():
         return _ssd_p(x, dt, A, B_, C, chunk)
     return ref.ssd_scan(x, dt, A, B_, C, chunk=chunk)[0]
 
@@ -741,7 +741,7 @@ def ssd_prefill_chunk(
     t = get_tuning("ssd_prefill_chunk", key=shape_class(s=x.shape[1]),
                    chunk=chunk)
     c = max(1, min(int(t["chunk"]), x.shape[1]))
-    if _pallas() and B_.shape[2] == 1:
+    if _pallas():
         # the kernel re-resolves its chunk from the tuning table; naming
         # this op's entry keeps the serving knob authoritative (idempotent
         # second lookup) instead of letting "ssd_scan" training tuning

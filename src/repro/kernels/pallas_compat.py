@@ -1,10 +1,7 @@
-"""Version-portable aliases for ``jax.experimental.pallas.tpu`` symbols.
+"""The ``jax.experimental.pallas.tpu`` symbols the kernels use.
 
-JAX renamed ``TPUCompilerParams`` -> ``CompilerParams`` and
-``TPUMemorySpace`` -> ``MemorySpace`` across releases.  Kernels import the
-names from here so the same source compiles against either side of the
-rename — the library-level analogue of the paper's single-source property
-(the kernel text does not change when the toolchain does).
+Kernels import these names from here, never from ``pallas.tpu`` directly,
+so a rename in JAX is absorbed in one file.
 
 This module is the *only* place in the library allowed to import
 ``jax.experimental.pallas.tpu`` — lint rule R001 (``repro.analysis``)
@@ -14,13 +11,12 @@ from __future__ import annotations
 
 from jax.experimental.pallas import tpu as pltpu  # repro-lint: disable=R001
 
-CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-MemorySpace = getattr(pltpu, "MemorySpace", None) or pltpu.TPUMemorySpace
+CompilerParams = pltpu.CompilerParams
+MemorySpace = pltpu.MemorySpace
 
 # Scratch-shape constructor for VMEM buffers: ``plc.VMEM((m, n), dtype)``.
 VMEM = MemorySpace.VMEM
 SMEM = MemorySpace.SMEM
 
-# Grid spec with scalar prefetch (decode kernels' page tables); name has
-# been stable but route it here so kernels never touch pltpu directly.
+# Grid spec with scalar prefetch (decode kernels' page tables).
 PrefetchScalarGridSpec = pltpu.PrefetchScalarGridSpec

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.registry import get_arch
+from repro.core.compile_cache import enable_compile_cache
 from repro.models import lm as LM
 from repro.models.model import build_model
 from repro.serving import ServingEngine, configs_from_flags
@@ -141,6 +142,7 @@ def main(argv=None) -> int:
                     help="verify decode path against teacher-forced forward")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_arch(args.arch)
     model = build_model(cfg)
     params = model.init_params(jax.random.PRNGKey(0))

@@ -53,7 +53,12 @@ def _init_layer_mamba(cfg):
     return f
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def init_params(cfg: ArchConfig, rng) -> Dict[str, Any]:
+    """Random params from ``rng``.  Jitted so each full-size f32 ``normal``
+    draw fuses into its scale-and-cast: at published widths only the
+    param-dtype outputs reach device memory, never an f32 temporary the
+    size of a stacked weight."""
     dt = cfg.dtype_()
     r_emb, r_layers, r_head, r_extra = jax.random.split(rng, 4)
     params: Dict[str, Any] = {

@@ -194,7 +194,7 @@ def test_flash_decode(b, hq, hkv, d, smax, ln, window):
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize(
     "B,S,H,P,N,chunk", [(2, 32, 4, 8, 16, 8), (1, 37, 3, 16, 32, 16),
-                        (2, 64, 2, 8, 8, 64)]
+                        (2, 64, 2, 8, 8, 64), (2, 1, 3, 8, 16, 64)]
 )
 def test_ssd_scan(B, S, H, P, N, chunk):
     set_tuning("ssd_scan", chunk=chunk)
@@ -207,6 +207,22 @@ def test_ssd_scan(B, S, H, P, N, chunk):
     ry, rhf = ref.ssd_scan(x, dt, A, Bm, C, chunk=chunk)
     np.testing.assert_allclose(y, ry, rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(hf, rhf, rtol=2e-4, atol=2e-4)
+
+
+def test_ssd_pallas_rejects_grouped_bc():
+    """Grouped B/C has no Pallas lowering: the serving dispatch raises
+    instead of quietly running the reference scan."""
+    from repro.core.policy import use_backend
+    from repro.kernels import ops
+
+    B, S, H, P, N = 1, 4, 4, 8, 8
+    x = jax.random.normal(key(0), (B, S, H, P))
+    dt = jax.nn.softplus(jax.random.normal(key(1), (B, S, H)))
+    A = -jnp.exp(jax.random.normal(key(2), (H,)))
+    Bm = jax.random.normal(key(3), (B, S, 2, N))
+    state = jnp.zeros((B, H, P, N))
+    with use_backend("pallas"), pytest.raises(ValueError, match="n_groups"):
+        ops.ssd_prefill_chunk(x, dt, A, Bm, Bm, state)
 
 
 def test_ssd_matches_sequential_decode():
