@@ -34,12 +34,6 @@ deadline storm.  The pressured engine must drain the doomed requests
 through the release path, finish the survivors with *bit-identical*
 tokens, and hand back every page and snapshot slot in both tiers.
 
-``--arrival poisson --rate R`` adds an open-loop cell: seeded
-exponential inter-arrival gaps on the wall clock, mixed priorities, a
-deliberately undersized paged pool — reporting p50/p99 TTFT plus the
-preemption/restore counters (latency under load; correctness under
-pressure is the ``--faults`` cell's job).
-
 ``--layout`` scopes the single-layout sections to one KV layout so a CI
 matrix cell (backend x layout) exercises exactly its own path; the
 inherently cross-layout ablation only runs under the default ``both``.
@@ -734,79 +728,6 @@ def run_pressure(args):
     return out
 
 
-def run_open_loop(args):
-    """The --arrival poisson section: open-loop latency under load.
-
-    Arrivals land at seeded exponential gaps on the wall clock whether or
-    not the engine has kept up (open loop), with mixed priorities and an
-    undersized paged pool, so queueing delay — and, under the squeeze,
-    preemption — shows up in the TTFT tail instead of being absorbed by a
-    closed feedback loop."""
-    cfg = get_arch(args.kv_arch)
-    model = build_model(cfg)
-    params = model.init_params(jax.random.PRNGKey(0))
-    n, gen = args.requests, args.gen
-    rng = np.random.default_rng(args.arrival_seed)
-    lo, hi = 4, 17
-    prompts = [
-        rng.integers(0, cfg.vocab_size,
-                     size=int(rng.integers(lo, hi))).tolist()
-        for _ in range(n)
-    ]
-    prios = [int(rng.integers(0, 2)) for _ in range(n)]
-    gaps = rng.exponential(1.0 / args.rate, size=n)
-    gaps[0] = 0.0
-    arrivals = np.cumsum(gaps)
-    max_len = hi + gen + 1
-    cache = CacheConfig()
-    if not cfg.is_attention_free:
-        from repro.serving.pager import pages_needed
-        page = args.page_size
-        full_pool = args.batch * (-(-max_len // page))
-        max_need = max(pages_needed(len(p) + gen, page) for p in prompts)
-        cache = CacheConfig(layout="paged", page_size=page,
-                            n_pages=max(max_need, (2 * full_pool) // 3),
-                            kv_dtype=args.kv_dtype)
-    eng = ServingEngine(model, params, batch=args.batch, max_len=max_len,
-                        cache=cache,
-                        config=EngineConfig(
-                            steps_per_sync=args.steps_per_sync))
-    with _audit_ctx(eng, args.audit):
-        for _ in range(args.batch):        # compile outside the clock
-            eng.submit([1, 2, 3], 2)
-        eng.run()
-        eng.reset_stats()
-        t0 = time.perf_counter()
-        nxt = 0
-        while nxt < n or len(eng.outputs) < n:
-            now = time.perf_counter() - t0
-            while nxt < n and arrivals[nxt] <= now:
-                eng.submit(prompts[nxt], gen, priority=prios[nxt])
-                nxt += 1
-            if eng.queue or any(r is not None for r in eng._slot_req):
-                eng.step()
-            elif nxt < n:
-                time.sleep(min(1e-3, max(0.0, arrivals[nxt] - now)))
-        dt = time.perf_counter() - t0
-    ttft = np.asarray(sorted(eng.ttft.values()))
-    row = {
-        "requests": n, "rate": args.rate, "seconds": dt,
-        "tok_s": eng.generated / dt,
-        "ttft_ms_p50": 1e3 * float(np.percentile(ttft, 50)),
-        "ttft_ms_p99": 1e3 * float(np.percentile(ttft, 99)),
-        "preemptions": eng.preemptions, "restores": eng.restores,
-    }
-    print(f"arch={args.kv_arch} requests={n} batch={args.batch} gen={gen} "
-          f"rate={args.rate}/s seed={args.arrival_seed}"
-          + (f" pool={cache.n_pages}p" if cache.layout == "paged" else ""))
-    print(f"  {'gen tok/s':>10} {'TTFT p50 ms':>12} {'TTFT p99 ms':>12} "
-          f"{'preempt':>8} {'restore':>8}")
-    print(f"  {row['tok_s']:>10.1f} {row['ttft_ms_p50']:>12.1f} "
-          f"{row['ttft_ms_p99']:>12.1f} {row['preemptions']:>8d} "
-          f"{row['restores']:>8d}")
-    return row
-
-
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mamba2-2.7b-smoke")
@@ -867,15 +788,6 @@ def main(argv=None):
                          "under a scripted FaultPlan (pool exhaustion, "
                          "cancel, deadline storm) with survivor "
                          "token-identity and conservation asserts")
-    ap.add_argument("--arrival", choices=["batch", "poisson"],
-                    default="batch",
-                    help="'poisson' adds an open-loop cell: seeded "
-                         "exponential inter-arrival gaps on the wall "
-                         "clock, p50/p99 TTFT + preemption counts")
-    ap.add_argument("--rate", type=float, default=16.0,
-                    help="open-loop arrival rate, requests/second")
-    ap.add_argument("--arrival-seed", type=int, default=0,
-                    help="seed for the open-loop arrival process")
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--smoke", action="store_true",
                     help="minimal sizes: CI driver-rot check, not a benchmark")
@@ -972,10 +884,6 @@ def main(argv=None):
         print(f"-- Pressure: preemption/spill + FaultPlan "
               f"(layout={args.layout}) --")
         out["pressure"] = run_pressure(args)
-    if args.arrival == "poisson":
-        print()
-        print("-- Open loop: poisson arrivals, TTFT under load --")
-        out["open_loop"] = run_open_loop(args)
     if args.json:
         import json
 

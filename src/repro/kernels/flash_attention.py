@@ -515,6 +515,14 @@ def flash_decode_pallas(
 # Paged decode: block-table-indirect KV pages, gathered in the index map
 # ---------------------------------------------------------------------------
 
+def _head_major(k_pages, v_pages):
+    """The page pools in the paged kernels' layout, ``(n_pages, Hkv, page,
+    D)``: a relayout of the whole pool on every call, staged under the
+    ``kv_pool`` name scope so a device trace can tell it from attention."""
+    with jax.named_scope("kv_pool"):
+        return k_pages.transpose(0, 2, 1, 3), v_pages.transpose(0, 2, 1, 3)
+
+
 def _flash_decode_paged_kernel(
     len_ref, bt_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
     *, scale, n_b, page, window,
@@ -570,8 +578,7 @@ def flash_decode_paged_pallas(
     n_b = block_table.shape[1]
     g = hq // hkv
     scale = scale if scale is not None else float(1.0 / np.sqrt(d))
-    kt = k_pages.transpose(0, 2, 1, 3)            # (n_pages, Hkv, page, D)
-    vt = v_pages.transpose(0, 2, 1, 3)
+    kt, vt = _head_major(k_pages, v_pages)
     qg = q.reshape(b, hkv, g, d)
     lens = jnp.broadcast_to(
         jnp.asarray(cache_len, jnp.int32).reshape(-1), (b,)
@@ -685,8 +692,7 @@ def flash_decode_paged_quant_pallas(
     bs = max(1, min(int(t["bs"]), page))
     while page % bs:
         bs //= 2
-    kt = k_pages.transpose(0, 2, 1, 3)            # (n_pages, Hkv, page, D)
-    vt = v_pages.transpose(0, 2, 1, 3)
+    kt, vt = _head_major(k_pages, v_pages)
     qg = q.reshape(b, hkv, g, d)
     lens = jnp.broadcast_to(
         jnp.asarray(cache_len, jnp.int32).reshape(-1), (b,)
@@ -934,8 +940,7 @@ def flash_prefill_chunk_paged_pallas(
     n_b = block_table.shape[1]
     g = hq // hkv
     scale = scale if scale is not None else float(1.0 / np.sqrt(d))
-    kt = k_pages.transpose(0, 2, 1, 3)            # (n_pages, Hkv, page, D)
-    vt = v_pages.transpose(0, 2, 1, 3)
+    kt, vt = _head_major(k_pages, v_pages)
     qg = q.reshape(b, c, hkv, g, d).transpose(0, 2, 3, 1, 4)
     qg = qg.reshape(b, hkv, g * c, d)
     starts = jnp.broadcast_to(
@@ -1050,8 +1055,7 @@ def flash_prefill_chunk_paged_quant_pallas(
     bs = max(1, min(int(t["bs"]), page))
     while page % bs:
         bs //= 2
-    kt = k_pages.transpose(0, 2, 1, 3)            # (n_pages, Hkv, page, D)
-    vt = v_pages.transpose(0, 2, 1, 3)
+    kt, vt = _head_major(k_pages, v_pages)
     qg = q.reshape(b, c, hkv, g, d).transpose(0, 2, 3, 1, 4)
     qg = qg.reshape(b, hkv, g * c, d)
     starts = jnp.broadcast_to(

@@ -760,7 +760,11 @@ def decode_step(
     pos = state["pos"]
     paged = "block_table" in state
     quant = paged and "ksc" in state    # trace-time: int8 KV pools
-    x = params["embed"][token].astype(cfg.dtype_())   # (B, d)
+    # the named scopes below (embed, layers, attention, mlp, ssm, kv_pool,
+    # lm_head) label every op of the step in the compiled program's op
+    # metadata, so a device trace can attribute time to each part
+    with jax.named_scope("embed"):
+        x = params["embed"][token].astype(cfg.dtype_())   # (B, d)
     # paged layout uses absolute positions (window masking in attention);
     # the contiguous layout ring-indexes sliding-window caches
     idx = pos if paged else _cache_index(cfg, pos)
@@ -853,13 +857,17 @@ def decode_step(
     if fam in ("dense", "moe"):
         def body(x, inp):
             p, kv = inp[0], inp[1:]
-            x, kv = attn_dec(p["attn"], x, kv)
-            x = moe_dec(p["moe"], x) if "moe" in p else mlp_dec(p["mlp"], x)
+            with jax.named_scope("attention"):
+                x, kv = attn_dec(p["attn"], x, kv)
+            with jax.named_scope("mlp"):
+                x = (moe_dec(p["moe"], x) if "moe" in p
+                     else mlp_dec(p["mlp"], x))
             return x, kv
-        x, kv_out = jax.lax.scan(
-            body, x,
-            (params["layers"],) + tuple(state[k] for k in kv_keys),
-        )
+        with jax.named_scope("layers"):
+            x, kv_out = jax.lax.scan(
+                body, x,
+                (params["layers"],) + tuple(state[k] for k in kv_keys),
+            )
         state = {**state, **dict(zip(kv_keys, kv_out))}
     elif fam == "ssm":
         # inactive (idle or spilled) rows must carry their recurrent state
@@ -869,13 +877,15 @@ def decode_step(
 
         def body(x, inp):
             p, s_ssm, s_conv = inp
-            x, s_ssm, s_conv = C.mamba_decode_block(
-                cfg, p["mamba"], x, s_ssm, s_conv, valid=val
-            )
+            with jax.named_scope("ssm"):
+                x, s_ssm, s_conv = C.mamba_decode_block(
+                    cfg, p["mamba"], x, s_ssm, s_conv, valid=val
+                )
             return x, (s_ssm, s_conv)
-        x, (ssm, conv) = jax.lax.scan(
-            body, x, (params["layers"], state["ssm"], state["conv"])
-        )
+        with jax.named_scope("layers"):
+            x, (ssm, conv) = jax.lax.scan(
+                body, x, (params["layers"], state["ssm"], state["conv"])
+            )
         state = {**state, "ssm": ssm, "conv": conv}
     elif fam == "hybrid":
         g = cfg.n_layers // cfg.attn_every
@@ -890,20 +900,24 @@ def decode_step(
 
             def inner(x, i2):
                 p, s1, s2 = i2
-                x, s1, s2 = C.mamba_decode_block(
-                    cfg, p["mamba"], x, s1, s2, valid=val
-                )
+                with jax.named_scope("ssm"):
+                    x, s1, s2 = C.mamba_decode_block(
+                        cfg, p["mamba"], x, s1, s2, valid=val
+                    )
                 return x, (s1, s2)
             x, (s_ssm, s_conv) = jax.lax.scan(inner, x, (gp, s_ssm, s_conv))
-            x, kv = attn_dec(params["shared_attn"], x, kv)
-            x = mlp_dec(params["shared_mlp"], x)
+            with jax.named_scope("attention"):
+                x, kv = attn_dec(params["shared_attn"], x, kv)
+            with jax.named_scope("mlp"):
+                x = mlp_dec(params["shared_mlp"], x)
             return x, (s_ssm, s_conv) + kv
 
-        x, out = jax.lax.scan(
-            group, x,
-            (params["groups"], ssm_g, conv_g)
-            + tuple(state[k] for k in kv_keys),
-        )
+        with jax.named_scope("layers"):
+            x, out = jax.lax.scan(
+                group, x,
+                (params["groups"], ssm_g, conv_g)
+                + tuple(state[k] for k in kv_keys),
+            )
         ssm, conv = out[0], out[1]
         state = {
             **state,
@@ -943,16 +957,18 @@ def decode_step(
     else:
         raise ValueError(fam)
 
-    x = C.norm(cfg, params["ln_f"], x)
-    w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = C.dense(x, w)
-    if active is not None and pos.ndim == 1:
-        state = {**state, "pos": pos + active.astype(jnp.int32)}
-    else:
-        state = {**state, "pos": pos + 1}
-    if snap_every and "snap_table" in state and pos.ndim == 1:
-        act = active if active is not None else jnp.ones_like(pos, bool)
-        state = _snap_capture(state, state["pos"], act, snap_every)
+    with jax.named_scope("lm_head"):
+        x = C.norm(cfg, params["ln_f"], x)
+        w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = C.dense(x, w)
+    with jax.named_scope("kv_pool"):
+        if active is not None and pos.ndim == 1:
+            state = {**state, "pos": pos + active.astype(jnp.int32)}
+        else:
+            state = {**state, "pos": pos + 1}
+        if snap_every and "snap_table" in state and pos.ndim == 1:
+            act = active if active is not None else jnp.ones_like(pos, bool)
+            state = _snap_capture(state, state["pos"], act, snap_every)
     return logits, state
 
 
@@ -1018,10 +1034,12 @@ def prefill_chunk(
         jnp.broadcast_to(jnp.asarray(width, jnp.int32).reshape(-1), (b,)),
         1, c,
     )
-    x = params["embed"][toks].astype(cfg.dtype_())     # (B, C, d)
-    offs = jnp.arange(c, dtype=jnp.int32)[None, :]
-    posmat = pos[:, None] + offs                       # (B, C) absolute pos
-    valid = active[:, None] & (offs < width[:, None])  # (B, C) real tokens
+    # named scopes as in decode_step
+    with jax.named_scope("embed"):
+        x = params["embed"][toks].astype(cfg.dtype_())     # (B, C, d)
+        offs = jnp.arange(c, dtype=jnp.int32)[None, :]
+        posmat = pos[:, None] + offs                       # (B, C) positions
+        valid = active[:, None] & (offs < width[:, None])  # (B, C) real
 
     if paged:
         from repro.serving import pager as PG
@@ -1101,23 +1119,28 @@ def prefill_chunk(
     if fam in ("dense", "moe"):
         def body(x, inp):
             p, kv = inp[0], inp[1:]
-            x, kv = attn_chunk(p["attn"], x, kv)
-            x = (C.moe_block(cfg, p["moe"], x) if "moe" in p
-                 else mlp_chunk(p["mlp"], x))
+            with jax.named_scope("attention"):
+                x, kv = attn_chunk(p["attn"], x, kv)
+            with jax.named_scope("mlp"):
+                x = (C.moe_block(cfg, p["moe"], x) if "moe" in p
+                     else mlp_chunk(p["mlp"], x))
             return x, kv
-        x, kv_out = jax.lax.scan(
-            body, x,
-            (params["layers"],) + tuple(state[k] for k in kv_keys),
-        )
+        with jax.named_scope("layers"):
+            x, kv_out = jax.lax.scan(
+                body, x,
+                (params["layers"],) + tuple(state[k] for k in kv_keys),
+            )
         state = {**state, **dict(zip(kv_keys, kv_out))}
     elif fam == "ssm":
         def body(x, inp):
             p, s_ssm, s_conv = inp
-            x, s_ssm, s_conv = mamba_chunk(p["mamba"], x, s_ssm, s_conv)
+            with jax.named_scope("ssm"):
+                x, s_ssm, s_conv = mamba_chunk(p["mamba"], x, s_ssm, s_conv)
             return x, (s_ssm, s_conv)
-        x, (ssm, conv) = jax.lax.scan(
-            body, x, (params["layers"], state["ssm"], state["conv"])
-        )
+        with jax.named_scope("layers"):
+            x, (ssm, conv) = jax.lax.scan(
+                body, x, (params["layers"], state["ssm"], state["conv"])
+            )
         state = {**state, "ssm": ssm, "conv": conv}
     elif fam == "hybrid":
         g = cfg.n_layers // cfg.attn_every
@@ -1131,18 +1154,22 @@ def prefill_chunk(
 
             def inner(x, i2):
                 p, s1, s2 = i2
-                x, s1, s2 = mamba_chunk(p["mamba"], x, s1, s2)
+                with jax.named_scope("ssm"):
+                    x, s1, s2 = mamba_chunk(p["mamba"], x, s1, s2)
                 return x, (s1, s2)
             x, (s_ssm, s_conv) = jax.lax.scan(inner, x, (gp, s_ssm, s_conv))
-            x, kv = attn_chunk(params["shared_attn"], x, kv)
-            x = mlp_chunk(params["shared_mlp"], x)
+            with jax.named_scope("attention"):
+                x, kv = attn_chunk(params["shared_attn"], x, kv)
+            with jax.named_scope("mlp"):
+                x = mlp_chunk(params["shared_mlp"], x)
             return x, (s_ssm, s_conv) + kv
 
-        x, out = jax.lax.scan(
-            group, x,
-            (params["groups"], ssm_g, conv_g)
-            + tuple(state[k] for k in kv_keys),
-        )
+        with jax.named_scope("layers"):
+            x, out = jax.lax.scan(
+                group, x,
+                (params["groups"], ssm_g, conv_g)
+                + tuple(state[k] for k in kv_keys),
+            )
         ssm, conv = out[0], out[1]
         state = {
             **state,
@@ -1157,21 +1184,23 @@ def prefill_chunk(
 
     # logits at each row's last real position (gather-then-norm: the final
     # norm and head are position-wise, so this equals the decode_step there)
-    w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    if logits_all:
-        # verifier path: every chunk position's logits (B, C, V); padding
-        # positions carry garbage the caller masks by width
-        h = C.norm(cfg, params["ln_f"], x)
-        logits = C.dense(h, w)
-    else:
-        last = jnp.take_along_axis(
-            x, (width - 1)[:, None, None], axis=1
-        )[:, 0]
-        h = C.norm(cfg, params["ln_f"], last)
-        logits = C.dense(h, w)
-    state = {**state, "pos": pos + jnp.where(active, width, 0)}
-    if snap_every and "snap_table" in state:
-        state = _snap_capture(state, state["pos"], active, snap_every)
+    with jax.named_scope("lm_head"):
+        w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        if logits_all:
+            # verifier path: every chunk position's logits (B, C, V);
+            # padding positions carry garbage the caller masks by width
+            h = C.norm(cfg, params["ln_f"], x)
+            logits = C.dense(h, w)
+        else:
+            last = jnp.take_along_axis(
+                x, (width - 1)[:, None, None], axis=1
+            )[:, 0]
+            h = C.norm(cfg, params["ln_f"], last)
+            logits = C.dense(h, w)
+    with jax.named_scope("kv_pool"):
+        state = {**state, "pos": pos + jnp.where(active, width, 0)}
+        if snap_every and "snap_table" in state:
+            state = _snap_capture(state, state["pos"], active, snap_every)
     return logits, state
 
 

@@ -110,6 +110,14 @@ from repro.serving.drafter import make_drafter
 from repro.serving.faults import FaultPlan
 from repro.serving.queue import Request, RequestQueue
 
+# Host spans of one ``ServingEngine.step`` cycle, as
+# ``jax.profiler.TraceAnnotation`` names: the whole cycle, then its parts
+# in order (admission, each chunked-prefill dispatch, the fused decode
+# dispatch, the one blocking readback, the release dispatch).  Outside a
+# profiler trace each costs one flag check.
+SPANS = ("engine.step", "engine.refill", "engine.prefill", "engine.decode",
+         "engine.harvest", "engine.release")
+
 
 class SlotState(NamedTuple):
     """Per-row serving control state — all device arrays, fixed shapes."""
@@ -191,45 +199,55 @@ def engine_step(model: Model, params, mstate, slots: SlotState,
     invariant the host-side prefix index relies on), and the model steps
     capture/carry the snapshot store.  The host mirror in ``step()``
     replays the same clip.
+
+    The feed gather runs under the ``embed`` name scope and sampling,
+    the token scatter and done-detection under ``sample`` (the model
+    step names its own parts).
     """
     b, max_len = slots.tokens.shape
     if chunk > 1:
-        limit = jnp.full((b,), chunk, jnp.int32)
-        if snap_every:
-            limit = jnp.minimum(
-                limit, snap_every - slots.progress % snap_every
+        with jax.named_scope("embed"):
+            limit = jnp.full((b,), chunk, jnp.int32)
+            if snap_every:
+                limit = jnp.minimum(
+                    limit, snap_every - slots.progress % snap_every
+                )
+            width = jnp.clip(slots.prompt_len - slots.progress, 1, limit)
+            gidx = jnp.clip(
+                slots.progress[:, None]
+                + jnp.arange(chunk, dtype=jnp.int32)[None, :],
+                0, max_len - 1,
             )
-        width = jnp.clip(slots.prompt_len - slots.progress, 1, limit)
-        gidx = jnp.clip(
-            slots.progress[:, None]
-            + jnp.arange(chunk, dtype=jnp.int32)[None, :],
-            0, max_len - 1,
-        )
-        toks = jnp.take_along_axis(slots.tokens, gidx, axis=1)
+            toks = jnp.take_along_axis(slots.tokens, gidx, axis=1)
         logits, mstate = model.prefill_chunk(params, mstate, toks, width,
                                              active=slots.active, cow=cow,
                                              snap_every=snap_every)
         stride = width
     else:
-        feed_idx = jnp.clip(slots.progress, 0, max_len - 1)
-        tok = jnp.take_along_axis(
-            slots.tokens, feed_idx[:, None], axis=1
-        )[:, 0]
+        with jax.named_scope("embed"):
+            feed_idx = jnp.clip(slots.progress, 0, max_len - 1)
+            tok = jnp.take_along_axis(
+                slots.tokens, feed_idx[:, None], axis=1
+            )[:, 0]
         logits, mstate = model.decode_step(params, mstate, tok,
                                            active=slots.active, cow=cow,
                                            snap_every=snap_every)
         stride = jnp.ones((b,), jnp.int32)
 
-    wpos = slots.progress + stride
-    nxt = _sample(logits, slots, wpos, temperature=temperature, top_k=top_k)
-    # scatter the sampled token where the next feed position is generated
-    writes = slots.active & (wpos >= slots.prompt_len) & (wpos < max_len)
-    col = jax.lax.broadcasted_iota(jnp.int32, (b, max_len), 1)
-    tokens = jnp.where(
-        writes[:, None] & (col == wpos[:, None]), nxt[:, None], slots.tokens
-    )
-    progress = slots.progress + stride * slots.active.astype(jnp.int32)
-    active = slots.active & (progress < slots.total_len - 1)
+    with jax.named_scope("sample"):
+        wpos = slots.progress + stride
+        nxt = _sample(logits, slots, wpos, temperature=temperature,
+                      top_k=top_k)
+        # scatter the sampled token where the next feed position is
+        # generated
+        writes = slots.active & (wpos >= slots.prompt_len) & (wpos < max_len)
+        col = jax.lax.broadcasted_iota(jnp.int32, (b, max_len), 1)
+        tokens = jnp.where(
+            writes[:, None] & (col == wpos[:, None]), nxt[:, None],
+            slots.tokens
+        )
+        progress = slots.progress + stride * slots.active.astype(jnp.int32)
+        active = slots.active & (progress < slots.total_len - 1)
     return mstate, SlotState(
         tokens=tokens,
         prompt_len=slots.prompt_len,
@@ -555,6 +573,13 @@ class ServingEngine:
         self.prefill_steps = 0  # chunked-prefill steps executed
         self.generated = 0      # tokens returned to callers
         self.prompt_tokens = 0  # prompt tokens ingested (host arithmetic)
+        # slot use of the two step programs (host mirror): token slots the
+        # chunked-prefill program ran (batch x prefill_chunk per dispatch)
+        # and the positions live rows advanced in them; row-steps the fused
+        # decode program ran (batch x steps_per_sync per call) and the
+        # positions live rows advanced in them
+        self.prefill_slots = self.prefill_positions = 0
+        self.decode_row_steps = self.decode_positions = 0
         # speculation counters: host mirrors of the device accumulator
         # (refreshed at each harvest sync — never a dedicated round-trip)
         self.spec_proposed = 0  # verifiable draft positions scored
@@ -1190,8 +1215,9 @@ class ServingEngine:
                     self._register_prefix(b, self._slot_req[b].tokens)
         return n
 
-    def _refill(self) -> int:
-        """Admit queued requests into free rows (one jitted masked write).
+    def _refill(self) -> List[int]:
+        """Admit queued requests into free rows (one jitted masked write);
+        returns the admitted request ids.
 
         Scheduler order per cycle: expire queued deadlines, restore
         spilled rows that fit, then admit the queue head while a free
@@ -1216,7 +1242,7 @@ class ServingEngine:
         self._try_restore(now)
         free = [b for b, r in enumerate(self._slot_req) if r is None]
         if not free or not self.queue:
-            return 0
+            return []
         new_tokens = np.zeros((self.batch, self.max_len), np.int32)
         new_plen = np.ones((self.batch,), np.int32)
         new_total = np.ones((self.batch,), np.int32)
@@ -1228,7 +1254,7 @@ class ServingEngine:
         registrations = []
         pending: Set[int] = set()   # rows admitted in this refill
         donors: Set[int] = set()    # their prefix donors
-        n = 0
+        admitted: List[int] = []
         for b in free:
             req = self.queue.peek()
             if req is None:
@@ -1262,9 +1288,9 @@ class ServingEngine:
             pending.add(b)
             if nblk > 0:
                 donors.add(src)
-            n += 1
-        if n == 0:
-            return 0
+            admitted.append(req.req_id)
+        if not admitted:
+            return []
         self._mstate, self._slots = self._admit(
             self._mstate, self._slots,
             jnp.asarray(new_tokens), jnp.asarray(new_plen),
@@ -1277,11 +1303,11 @@ class ServingEngine:
         # blocks only materialize in the _admit call above)
         for b, toks in registrations:
             self._register_prefix(b, toks)
-        return n
+        return admitted
 
     # -- serving loop --------------------------------------------------------
 
-    def _advance_mirror(self, widths: List[int]) -> List[int]:
+    def _advance_mirror(self, widths: List[int]):
         """Replay one device step's progress update on the host mirror.
 
         ``widths[b]`` is the stride row b advanced (a chunk width for a
@@ -1289,11 +1315,13 @@ class ServingEngine:
         decode case over-counts past done-detection, which the
         ``total_len - 1`` clamp absorbs exactly like the device's
         ``active`` mask).  Accumulates prompt-ingestion counts and returns
-        the req_ids whose first generated token was produced by this step
-        (TTFT is stamped by the caller at the next device sync, when that
-        token actually exists).
+        ``(crossed, advanced)``: the req_ids whose first generated token
+        was produced by this step (TTFT is stamped by the caller at the
+        next device sync, when that token actually exists), and the
+        positions the live rows advanced in all.
         """
         crossed: List[int] = []
+        advanced = 0
         for b, req in enumerate(self._slot_req):
             if req is None or self._row_spilled[b]:
                 # a spilled row's lane is device-inactive: no progress
@@ -1308,7 +1336,8 @@ class ServingEngine:
             if p < req.prompt_len <= np_:
                 crossed.append(req.req_id)
             self._row_progress[b] = np_
-        return crossed
+            advanced += np_ - p
+        return crossed, advanced
 
     def _chunk_limit(self, progress: int) -> int:
         """Host mirror of ``engine_step``'s chunk-width cap: snapshot
@@ -1339,161 +1368,189 @@ class ServingEngine:
         ``steps_per_sync`` fused decode steps, then a single host
         readback to harvest finished — and drain cancelled / expired /
         poisoned — rows.  Returns the number of requests completed this
-        cycle."""
-        self._apply_faults()
-        self._cycle += 1
-        self._refill()
-        if not any(r is not None for r in self._slot_req):
-            return 0
-        crossed: List[int] = []
-        if self._prefill is not None:
-            # prompt ingestion: chunked steps, back-to-back dispatches, no
-            # host sync — the mirror knows each row's width without one.
-            # Decode-phase rows ride along one token per chunk step.
-            # ``prefill_budget`` caps the chunk steps per cycle so a long
-            # prompt cannot starve resident decodes of a whole harvest
-            # interval; leftover prompt tokens continue next cycle or
-            # token-by-token inside the fused decode call below.
-            nchunks = 0
-            while self._prompt_phase_rows() and (
-                    not self.prefill_budget
-                    or nchunks < self.prefill_budget):
-                widths = [
-                    max(1, min(self._chunk_limit(self._row_progress[b]),
-                               req.prompt_len - self._row_progress[b]))
-                    if req is not None else 1
-                    for b, req in enumerate(self._slot_req)
-                ]
-                self._mstate, self._slots = self._prefill(
-                    self.params, self._mstate, self._slots
+        cycle.
+
+        Each part runs under its profiler span (``SPANS``), so a device
+        trace can put every idle gap down to the host's part of a cycle.
+        """
+        annotate = jax.profiler.TraceAnnotation
+        with annotate("engine.step", cycle=self._cycle + 1):
+            self._apply_faults()
+            self._cycle += 1
+            with annotate("engine.refill") as span:
+                admitted = self._refill()
+                if admitted and annotate.is_enabled():
+                    span.set_metadata(
+                        admitted=" ".join(map(str, admitted)))
+            if not any(r is not None for r in self._slot_req):
+                return 0
+            crossed: List[int] = []
+            if self._prefill is not None:
+                # prompt ingestion: chunked steps, back-to-back dispatches,
+                # no host sync — the mirror knows each row's width without
+                # one.  Decode-phase rows ride along one token per chunk
+                # step.  ``prefill_budget`` caps the chunk steps per cycle
+                # so a long prompt cannot starve resident decodes of a
+                # whole harvest interval; leftover prompt tokens continue
+                # next cycle or token-by-token inside the fused decode call
+                # below.
+                nchunks = 0
+                while self._prompt_phase_rows() and (
+                        not self.prefill_budget
+                        or nchunks < self.prefill_budget):
+                    with annotate("engine.prefill"):
+                        widths = [
+                            max(1, min(
+                                self._chunk_limit(self._row_progress[b]),
+                                req.prompt_len - self._row_progress[b]))
+                            if req is not None else 1
+                            for b, req in enumerate(self._slot_req)
+                        ]
+                        self._mstate, self._slots = self._prefill(
+                            self.params, self._mstate, self._slots
+                        )
+                        self.prefill_steps += 1
+                        nchunks += 1
+                        new, advanced = self._advance_mirror(widths)
+                        crossed += new
+                        self.prefill_slots += self.batch * self.prefill_chunk
+                        self.prefill_positions += advanced
+            with annotate("engine.decode"):
+                # rows a budget stop left mid-prompt are frozen for the
+                # fused decode call (see ``_step_n``): advancing them
+                # token-by-token would change their chunk partitioning,
+                # and with it the logits
+                run = np.ones((self.batch,), bool)
+                if self._prefill is not None:
+                    for b, req in enumerate(self._slot_req):
+                        if (req is not None and not self._row_spilled[b]
+                                and req.prompt_len - self._row_progress[b]
+                                >= 2):
+                            run[b] = False
+                self.steps += self.steps_per_sync
+                self.decode_row_steps += self.batch * self.steps_per_sync
+                if self._spec_n is not None:
+                    # draft-and-verify decode: per-row strides are
+                    # data-dependent (accepted lengths), so the host mirror
+                    # is refreshed from the harvest readback below instead
+                    # of replayed arithmetically
+                    self._mstate, self._slots, self._acc = self._spec_n(
+                        self.params, self._mstate, self._slots, self._acc,
+                        jnp.asarray(run),
+                    )
+                else:
+                    self._mstate, self._slots = self._step_n(
+                        self.params, self._mstate, self._slots,
+                        jnp.asarray(run)
+                    )
+                    new, advanced = self._advance_mirror(
+                        [self.steps_per_sync if run[b] else 0
+                         for b in range(self.batch)]
+                    )
+                    crossed += new
+                    self.decode_positions += advanced
+            # the one host sync of the cycle (allocator tops — and, under
+            # speculation, per-row progress and the accept counter — ride
+            # along; no extra round-trips)
+            fetch = [self._slots.active, self._slots.tokens]
+            i_prog = i_acc = i_page = i_snap = -1
+            if self._spec_n is not None:
+                fetch.append(self._slots.progress)
+                i_prog = len(fetch) - 1
+                fetch.append(self._acc)
+                i_acc = len(fetch) - 1
+            if self._paged:
+                fetch.append(self._mstate["page_top"])
+                i_page = len(fetch) - 1
+            if self._snap:
+                fetch.append(self._mstate["snap_top"])
+                i_snap = len(fetch) - 1
+            with annotate("engine.harvest"):
+                got = list(jax.device_get(tuple(fetch)))
+            active, tokens = got[0], got[1]
+            if self._spec_n is not None:
+                # mirror refresh: the device's per-row progress is the
+                # truth under speculation; the same deltas the
+                # deterministic replay would have produced (ingestion
+                # counts, TTFT crossings, positions advanced) are recovered
+                # from old-vs-new
+                devprog = got[i_prog]
+                for b, req in enumerate(self._slot_req):
+                    if req is None:
+                        continue
+                    old = self._row_progress[b]
+                    dev = int(devprog[b])
+                    plen = req.prompt_len
+                    self.prompt_tokens += min(dev, plen) - min(old, plen)
+                    if old < plen <= dev:
+                        crossed.append(req.req_id)
+                    self._row_progress[b] = dev
+                    self.decode_positions += dev - old
+                acc = got[i_acc]
+                self.spec_accepted = int(acc[0])
+                self.spec_proposed = int(acc[1])
+                self.spec_emitted = int(acc[2])
+            if self._paged:
+                self.peak_pages_in_use = max(
+                    self.peak_pages_in_use, self.n_pages - int(got[i_page])
                 )
-                self.prefill_steps += 1
-                nchunks += 1
-                crossed += self._advance_mirror(widths)
-        # rows a budget stop left mid-prompt are frozen for the fused
-        # decode call (see ``_step_n``): advancing them token-by-token
-        # would change their chunk partitioning, and with it the logits
-        run = np.ones((self.batch,), bool)
-        if self._prefill is not None:
-            for b, req in enumerate(self._slot_req):
-                if (req is not None and not self._row_spilled[b]
-                        and req.prompt_len - self._row_progress[b] >= 2):
-                    run[b] = False
-        if self._spec_n is not None:
-            # draft-and-verify decode: per-row strides are data-dependent
-            # (accepted lengths), so the host mirror is refreshed from
-            # the harvest readback below instead of replayed
-            # arithmetically
-            self._mstate, self._slots, self._acc = self._spec_n(
-                self.params, self._mstate, self._slots, self._acc,
-                jnp.asarray(run),
-            )
-            self.steps += self.steps_per_sync
-        else:
-            self._mstate, self._slots = self._step_n(
-                self.params, self._mstate, self._slots, jnp.asarray(run)
-            )
-            self.steps += self.steps_per_sync
-            crossed += self._advance_mirror(
-                [self.steps_per_sync if run[b] else 0
-                 for b in range(self.batch)]
-            )
-        # the one host sync of the cycle (allocator tops — and, under
-        # speculation, per-row progress and the accept counter — ride
-        # along; no extra round-trips)
-        fetch = [self._slots.active, self._slots.tokens]
-        i_prog = i_acc = i_page = i_snap = -1
-        if self._spec_n is not None:
-            fetch.append(self._slots.progress)
-            i_prog = len(fetch) - 1
-            fetch.append(self._acc)
-            i_acc = len(fetch) - 1
-        if self._paged:
-            fetch.append(self._mstate["page_top"])
-            i_page = len(fetch) - 1
-        if self._snap:
-            fetch.append(self._mstate["snap_top"])
-            i_snap = len(fetch) - 1
-        got = list(jax.device_get(tuple(fetch)))
-        active, tokens = got[0], got[1]
-        if self._spec_n is not None:
-            # mirror refresh: the device's per-row progress is the truth
-            # under speculation; the same deltas the deterministic replay
-            # would have produced (ingestion counts, TTFT crossings) are
-            # recovered from old-vs-new
-            devprog = got[i_prog]
+            if self._snap:
+                self.peak_snaps_in_use = max(
+                    self.peak_snaps_in_use,
+                    self.n_snap_slots - int(got[i_snap])
+                )
+            # the readback above materialized every token this cycle
+            # produced, so first-token latencies are stamped here, not at
+            # dispatch (the pop keeps the submit-time ledger bounded by
+            # pending requests)
+            now = time.perf_counter()
+            for rid in crossed:
+                t0 = self._t_submit.pop(rid, None)
+                if t0 is not None:
+                    self.ttft.setdefault(rid, now - t0)
+            finished = 0
+            release = np.zeros((self.batch,), bool)
+            drained = False
             for b, req in enumerate(self._slot_req):
                 if req is None:
                     continue
-                old = self._row_progress[b]
-                dev = int(devprog[b])
-                plen = req.prompt_len
-                self.prompt_tokens += min(dev, plen) - min(old, plen)
-                if old < plen <= dev:
-                    crossed.append(req.req_id)
-                self._row_progress[b] = dev
-            acc = got[i_acc]
-            self.spec_accepted = int(acc[0])
-            self.spec_proposed = int(acc[1])
-            self.spec_emitted = int(acc[2])
-        if self._paged:
-            self.peak_pages_in_use = max(
-                self.peak_pages_in_use, self.n_pages - int(got[i_page])
-            )
-        if self._snap:
-            self.peak_snaps_in_use = max(
-                self.peak_snaps_in_use, self.n_snap_slots - int(got[i_snap])
-            )
-        # the readback above materialized every token this cycle produced,
-        # so first-token latencies are stamped here, not at dispatch (the
-        # pop keeps the submit-time ledger bounded by pending requests)
-        now = time.perf_counter()
-        for rid in crossed:
-            t0 = self._t_submit.pop(rid, None)
-            if t0 is not None:
-                self.ttft.setdefault(rid, now - t0)
-        finished = 0
-        release = np.zeros((self.batch,), bool)
-        drained = False
-        for b, req in enumerate(self._slot_req):
-            if req is None:
-                continue
-            rid = req.req_id
-            t = self._deadline.get(rid)
-            if rid in self._cancel_req:
-                self.cancelled.add(rid)
-            elif rid in self._poison_req:
-                self.poisoned.add(rid)
-            elif t is not None and now >= t:
-                self.expired.add(rid)
-            elif self._row_spilled[b] or active[b]:
-                continue    # still running (or parked on the host tier)
-            else:
-                # finished for real: the generated span is the payload
-                out = tokens[b, req.prompt_len : req.total_len].copy()
-                self.outputs[rid] = out
-                self.generated += out.size
+                rid = req.req_id
+                t = self._deadline.get(rid)
+                if rid in self._cancel_req:
+                    self.cancelled.add(rid)
+                elif rid in self._poison_req:
+                    self.poisoned.add(rid)
+                elif t is not None and now >= t:
+                    self.expired.add(rid)
+                elif self._row_spilled[b] or active[b]:
+                    continue    # still running (or parked on the host tier)
+                else:
+                    # finished for real: the generated span is the payload
+                    out = tokens[b, req.prompt_len : req.total_len].copy()
+                    self.outputs[rid] = out
+                    self.generated += out.size
+                    self._drop_row(b)
+                    release[b] = True
+                    finished += 1
+                    continue
+                # cancelled / poisoned / past-deadline: no payload; the row
+                # — device-active, mid-prefill, or spilled — drains through
+                # the same release path, surrendering pages and snapshot
+                # slots in every tier
                 self._drop_row(b)
                 release[b] = True
-                finished += 1
-                continue
-            # cancelled / poisoned / past-deadline: no payload; the row —
-            # device-active, mid-prefill, or spilled — drains through the
-            # same release path, surrendering pages and snapshot slots in
-            # every tier
-            self._drop_row(b)
-            release[b] = True
-            drained = True
-        if np.any(release) and (self._paged or self._snap or drained):
-            # free-on-completion: the rows' pages and snapshot slots
-            # (device *and* host tiers) return to their pools now, not
-            # when the slot happens to be refilled; drained rows
-            # additionally need their lanes deactivated (a cancelled row
-            # may still be device-active)
-            self._mstate, self._slots = self._release(
-                self._mstate, self._slots, jnp.asarray(release)
-            )
-        return finished
+                drained = True
+            if np.any(release) and (self._paged or self._snap or drained):
+                # free-on-completion: the rows' pages and snapshot slots
+                # (device *and* host tiers) return to their pools now, not
+                # when the slot happens to be refilled; drained rows
+                # additionally need their lanes deactivated (a cancelled
+                # row may still be device-active)
+                with annotate("engine.release"):
+                    self._mstate, self._slots = self._release(
+                        self._mstate, self._slots, jnp.asarray(release)
+                    )
+            return finished
 
     def _drop_row(self, b: int) -> None:
         """Host-mirror bookkeeping for a row leaving the batch (finished
@@ -1529,6 +1586,8 @@ class ServingEngine:
         self.ttft.clear()
         self.steps = self.prefill_steps = 0
         self.generated = self.prompt_tokens = 0
+        self.prefill_slots = self.prefill_positions = 0
+        self.decode_row_steps = self.decode_positions = 0
         self.spec_proposed = self.spec_accepted = self.spec_emitted = 0
         if self._acc is not None:
             self._acc = jnp.zeros((3,), jnp.int32)
@@ -1564,6 +1623,10 @@ class ServingEngine:
             "generated_tokens": float(self.generated),
             "prompt_tokens": float(self.prompt_tokens),
             "batch": float(self.batch),
+            "prefill_slots": float(self.prefill_slots),
+            "prefill_positions": float(self.prefill_positions),
+            "decode_row_steps": float(self.decode_row_steps),
+            "decode_positions": float(self.decode_positions),
         }
         if self._paged:
             out["kv_pages"] = float(self.n_pages)

@@ -200,10 +200,22 @@ chunked step can never find the free list empty for a live request.
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+
+def _kv_pool(fn):
+    """Stage every device op of ``fn`` under the ``kv_pool`` name scope,
+    so the compiled program's op metadata (and a device trace) names the
+    pool's allocator, copy and write work wherever it is called from."""
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        with jax.named_scope("kv_pool"):
+            return fn(*args, **kwargs)
+    return scoped
 
 
 class PagerState(NamedTuple):
@@ -252,6 +264,7 @@ def pages_needed(total_len: int, page_size: int) -> int:
     return max(1, -(-(total_len - 1) // page_size))
 
 
+@_kv_pool
 def alloc_on_write(
     pager: PagerState,
     block_table: jax.Array,          # (B, max_blocks) int32
@@ -291,6 +304,7 @@ def alloc_on_write(
     return PagerState(pager.free, top, rc), block_table
 
 
+@_kv_pool
 def alloc_range(
     pager: PagerState,
     block_table: jax.Array,          # (B, max_blocks) int32
@@ -334,6 +348,7 @@ def alloc_range(
     return pager, block_table
 
 
+@_kv_pool
 def release_rows(
     pager: PagerState,
     block_table: jax.Array,   # (B, max_blocks) int32
@@ -359,6 +374,7 @@ def release_rows(
     return PagerState(free, top, rc), block_table
 
 
+@_kv_pool
 def release_tail(
     pager: PagerState,
     block_table: jax.Array,   # (B, max_blocks) int32
@@ -398,6 +414,7 @@ def release_tail(
     return PagerState(free, top, rc), block_table
 
 
+@_kv_pool
 def spill_rows(
     pager: PagerState,
     table: jax.Array,         # (B, max_blocks) int32: device-tier table
@@ -444,6 +461,7 @@ def spill_rows(
     return pager, table, PagerState(hpager.free, h_top, h_rc), htable, src, dst
 
 
+@_kv_pool
 def restore_rows(
     pager: PagerState,
     table: jax.Array,         # (B, max_blocks) int32: device-tier table
@@ -481,6 +499,7 @@ def restore_rows(
     return PagerState(pager.free, top, rc), table, hpager, htable, src, dst
 
 
+@_kv_pool
 def copy_pages(
     dst_pool: jax.Array,
     src_pool: jax.Array,
@@ -508,6 +527,7 @@ def copy_pages(
     raise ValueError(f"copy_pages: unsupported page axis {axis}")
 
 
+@_kv_pool
 def share_prefix(
     pager: PagerState,
     block_table: jax.Array,   # (B, max_blocks) int32
@@ -539,6 +559,7 @@ def share_prefix(
     return PagerState(pager.free, pager.top, pager.rc + inc), block_table
 
 
+@_kv_pool
 def cow_on_write(
     pager: PagerState,
     block_table: jax.Array,          # (B, max_blocks) int32
@@ -598,6 +619,7 @@ def cow_on_write(
     return PagerState(free, top, rc), block_table, old, dst, limit, grant
 
 
+@_kv_pool
 def copy_page_prefix(
     pool: jax.Array,    # (stacks, n_pages, page_size, Hkv, hd)
     src: jax.Array,     # (B,) int32 page ids (n_pages sentinel = skip row)
@@ -617,6 +639,7 @@ def copy_page_prefix(
     return pool.at[:, dst].set(content, mode="drop")
 
 
+@_kv_pool
 def write_page(
     pool: jax.Array,                 # (n_pages, page_size, Hkv, hd)
     new: jax.Array,                  # (B, Hkv, hd): one token per row
@@ -645,6 +668,7 @@ def write_page(
     )
 
 
+@_kv_pool
 def write_page_chunk(
     pool: jax.Array,                 # (n_pages, page_size, Hkv, hd)
     new: jax.Array,                  # (B, C, Hkv, hd): C tokens per row
@@ -695,6 +719,7 @@ def _quant_safe(scale: jax.Array) -> jax.Array:
     return jnp.where(scale > 0, scale, 1.0)
 
 
+@_kv_pool
 def write_page_quant(
     pool: jax.Array,                 # (n_pages, page_size, Hkv, hd) int8
     scale: jax.Array,                # (n_pages, Hkv) f32
@@ -744,6 +769,7 @@ def write_page_quant(
     return pool, scale
 
 
+@_kv_pool
 def write_page_chunk_quant(
     pool: jax.Array,                 # (n_pages, page_size, Hkv, hd) int8
     scale: jax.Array,                # (n_pages, Hkv) f32
@@ -805,6 +831,7 @@ def write_page_chunk_quant(
     return pool, scale
 
 
+@_kv_pool
 def copy_page_scale(
     scales: jax.Array,  # (stacks, n_pages, Hkv) f32
     src: jax.Array,     # (B,) int32 page ids (n_pages sentinel = skip row)
