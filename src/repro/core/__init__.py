@@ -27,8 +27,10 @@ from repro.core.registry import (
     get_tuning,
     last_resolved,
     list_ops,
+    last_plan,
     load_tuning_table,
     register_op,
+    resolved_plans,
     set_tuning,
     tuning_overrides,
     tuning_table,
@@ -53,8 +55,10 @@ __all__ = [
     "get_tuning",
     "last_resolved",
     "list_ops",
+    "last_plan",
     "load_tuning_table",
     "register_op",
+    "resolved_plans",
     "set_tuning",
     "tuning_overrides",
     "tuning_table",

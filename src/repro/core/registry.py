@@ -130,6 +130,8 @@ def coverage() -> Dict[str, bool]:
 
 _TABLE: Optional[Dict[tuple, Dict[str, Any]]] = None
 _LAST_RESOLVED: Dict[str, str] = {}
+_PLANS: Dict[str, Dict[tuple, tuple]] = {}
+_LAST_PLAN: Dict[str, Any] = {}
 
 
 def _table() -> Dict[tuple, Dict[str, Any]]:
@@ -233,6 +235,29 @@ def last_resolved(op: str) -> Optional[str]:
     classify shapes exactly like the kernel call sites do.
     """
     return _LAST_RESOLVED.get(op)
+
+
+def record_plan(op: str, shape: tuple, plan: Any) -> None:
+    """Record the blocks (a ``NamedTuple``) that a traced call of ``op`` at
+    ``shape`` resolved."""
+    _PLANS.setdefault(op, {})[tuple(shape)] = plan
+    _LAST_PLAN[op] = plan
+
+
+def resolved_plans(op: str) -> Dict[tuple, tuple]:
+    """``shape -> plan`` of every call of ``op`` traced in this process, the
+    latest plan per shape; for the GEMM ``(m, k, n) -> (bm, bn, bk, grid
+    steps)``.  Like ``last_resolved``, a self-check aid: a reader counts the
+    grid steps of each program from it."""
+    return dict(_PLANS.get(op, {}))
+
+
+def last_plan(op: str) -> Dict[str, int]:
+    """The fields of the plan the most recent traced call of ``op``
+    recorded, ``{}`` where it records none.  The autotuner sweeps a
+    kernel whose blocks come from the call's shape around this plan."""
+    plan = _LAST_PLAN.get(op)
+    return plan._asdict() if plan is not None else {}
 
 
 def clear_tuning() -> None:

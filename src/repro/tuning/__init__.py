@@ -51,6 +51,9 @@ i.e. the persisted table supersedes the hand-set call-site defaults for
 every shape class it covers, while an explicit ``set_tuning`` override
 (tests, experiments) always beats the table.  A ``key=`` lookup that
 misses every layer falls back cleanly to the call-site defaults.
+gemm's call-site defaults are not literals but the blocks it plans from
+each call's shape (``kernels.gemm.plan_blocks``, recorded in
+``registry.resolved_plans``); the autotuner sweeps around that plan.
 
 Table format (``tuning_table.json``, schema 1)
 ----------------------------------------------

@@ -43,6 +43,7 @@ import numpy as np
 
 from repro.core.policy import use_backend
 from repro.core.registry import (
+    last_plan,
     last_resolved,
     list_ops,
     tuning_overrides,
@@ -462,7 +463,11 @@ def sweep_key(
                 "diverged from the kernel's"
             )
         best_ms, best_params = default_ms, None
-        for cand in candidates(knobs, smoke):
+        # a kernel that plans its blocks from the call's shape (gemm) has
+        # no literal defaults: sweep around the plan its baseline resolved
+        base = {**knobs, **{k: v for k, v in last_plan(key).items()
+                            if k in knobs}}
+        for cand in candidates(base, smoke):
             with tuning_overrides(key, cls, **cand):
                 try:
                     ms = measure(thunk, repeats)
@@ -549,7 +554,7 @@ def run_autotune(
     with use_backend("pallas"), tuning_table(None):
         for key in sweep_keys:
             knobs = sites.get(key, {})
-            if not any(isinstance(v, int) for v in knobs.values()):
+            if not knobs:
                 log(f"  {key}: no derivable knobs, skipped")
                 continue
             log(f"  {key}: knobs {knobs}")

@@ -39,7 +39,13 @@ def key(i=0):
 # ---------------------------------------------------------------------------
 @pytest.mark.parametrize(
     "m,k,n", [(128, 128, 128), (200, 300, 170), (7, 5, 3), (256, 512, 384),
-              (1, 1024, 8), (129, 257, 129)]
+              (1, 1024, 8), (129, 257, 129),
+              # planned blocks: M of 1, 16 and 2048; K = 128 * 43 (like
+              # 11008); N = 128 * 37 (like 151936), tiled with an edge
+              # block; K under 128; an edge block along M; a K that is
+              # no multiple of 128 and too long to take whole (padded)
+              (1, 256, 384), (16, 5504, 256), (2048, 256, 4736),
+              (16, 96, 4736), (2100, 128, 384), (8, 30001, 128)]
 )
 def test_gemm_shapes(m, k, n):
     a = jax.random.normal(key(0), (m, k), jnp.float32)
@@ -65,6 +71,56 @@ def test_gemm_tuning_registry():
     b = jax.random.normal(key(1), (96, 72), jnp.float32)
     np.testing.assert_allclose(gemm_pallas(a, b), ref.gemm(a, b), rtol=1e-4,
                                atol=1e-3)
+
+
+# qwen2.5-3b's projections at 16 rows (decode) and 16 x 128 (chunked
+# prefill), and its tied head at 16 rows
+_CELL_WIDTHS = [(2048, 2048), (2048, 256), (2048, 11008), (11008, 2048)]
+_CELL_SHAPES = ([(16, k, n) for k, n in _CELL_WIDTHS]
+                + [(2048, k, n) for k, n in _CELL_WIDTHS]
+                + [(16, 2048, 151936)])
+
+
+def test_gemm_plan_at_cell_shapes():
+    from repro.core import last_plan, resolved_plans
+    from repro.kernels.gemm import VMEM_BUDGET, plan_blocks, vmem_bytes
+
+    steps = {}
+    for m, k, n in _CELL_SHAPES:
+        p = plan_blocks(m, k, n, 2, 2)
+        assert k % p.bk == 0, (m, k, n, p)
+        assert vmem_bytes(p.bm, p.bn, p.bk, 2, 2) <= VMEM_BUDGET, (m, k, n, p)
+        # the call pads and copies no operand, and records its plan
+        jaxpr = str(jax.make_jaxpr(
+            lambda a, b: gemm_pallas(a, b, interpret=False))(
+                jax.ShapeDtypeStruct((m, k), jnp.bfloat16),
+                jax.ShapeDtypeStruct((k, n), jnp.bfloat16)))
+        assert " pad[" not in jaxpr and "copy" not in jaxpr, (m, k, n)
+        assert resolved_plans("gemm")[(m, k, n)] == p
+        assert last_plan("gemm") == p._asdict()
+        steps[(m, k, n)] = p.steps
+
+    def program(m):  # q, k, v, o, gate, up, down of 36 layers, and the head
+        q, kv, up, down = (steps[(m, k, n)] for k, n in _CELL_WIDTHS)
+        return 36 * (2 * q + 2 * kv + 2 * up + down) + steps[(16, 2048, 151936)]
+
+    # a tenth of the grid steps of 128 x 128 x 128 blocks, or fewer
+    assert program(2048) <= 30_000
+    assert program(16) <= 5_000
+
+
+def test_autotune_sweeps_gemm_around_its_plan():
+    """gemm's blocks come from its plan, not literal defaults: the sweep
+    still runs, around the plan each case's baseline resolved."""
+    from repro.tuning import table as tt
+    from repro.tuning.autotune import run_autotune
+
+    lines = []
+    doc = run_autotune(smoke=True, only=["gemm"], repeats=1, log=lines.append)
+    assert tt.validate(doc) == []
+    assert {c["op"]: c["status"] for c in doc["cells"]}["matmul"] == "swept"
+    assert not any("no derivable knobs" in line for line in lines)
+    assert any("gemm[" in line for line in lines), lines
 
 
 # ---------------------------------------------------------------------------

@@ -80,7 +80,17 @@ def _compile(fn, one_chip, *shapes):
     (B, D_MODEL, HQ * HD),       # decode q projection
     (B, D_MODEL, VOCAB),         # tied LM head
     (B * C, D_MODEL, D_FF),      # prefill MLP up/gate
-], ids=["decode_proj", "lm_head", "prefill_mlp"])
+    # the benchmark cell's 16 rows: decode, and 16 x 128 in chunked prefill
+    (16, D_MODEL, HQ * HD),
+    (16, D_MODEL, HKV * HD),
+    (16, D_MODEL, D_FF),
+    (16, D_FF, D_MODEL),
+    (16 * C, D_MODEL, D_FF),
+    (16 * C, D_FF, D_MODEL),
+    (16, D_MODEL, VOCAB),
+], ids=["decode_proj", "lm_head", "prefill_mlp", "decode16_q", "decode16_kv",
+        "decode16_up", "decode16_down", "prefill16_up", "prefill16_down",
+        "decode16_head"])
 def test_gemm(one_chip, m, k, n):
     _compile(lambda a, b: gemm_pallas(a, b, interpret=False), one_chip,
              ((m, k), BF16), ((k, n), BF16))
