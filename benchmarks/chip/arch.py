@@ -1,33 +1,32 @@
-"""Sizes of a configuration file, under one set of names.
+"""A configuration file and the family module that reads it.
 
 ``configs/<name>.json`` keeps the keys of the model's published
-``config.json``; this module reads them into :class:`Arch`, which the
-weight maker, the reference and the work functions share.  It imports
-nothing of the program under test.
+``config.json`` and names its ``family``, one of the program's
+``ArchConfig.family`` names.  ``families/<family>.py`` reads the file into
+that family's ``Arch`` (a frozen dataclass of sizes with at least
+``n_layers``, ``d_model``, ``vocab`` and ``dtype``) and provides what the
+benchmark needs of one kind of model:
+
+* ``arch_of(cfg)``: the configuration's ``Arch``;
+* ``program_sizes(arch)``: ``{ArchConfig attribute: value}`` that the
+  program's model must have;
+* ``shapes(arch)``: the weight table ``weights.make`` draws from;
+* ``logits(arch, params, tokens, positions, quantize="")``: the plain
+  float32 reference at ``highest`` precision;
+* ``window_work(arch, recs, chunk, dispatches)``: ``work.Work`` by layer
+  of the window's requests, and ``model_flops``.
+
+A family module imports nothing of the program under test.
 """
 from __future__ import annotations
 
+import importlib.util
 import json
-from dataclasses import dataclass
+import sys
 from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
-
-
-@dataclass(frozen=True)
-class Arch:
-    """A dense decoder: grouped-query attention and a SwiGLU MLP."""
-    n_layers: int
-    d_model: int
-    vocab: int
-    n_heads: int
-    n_kv_heads: int
-    head_dim: int
-    d_ff: int
-    rope_theta: float
-    qkv_bias: bool
-    eps: float
-    dtype: str             # weights and activations as served
+_FAMILIES: dict = {}
 
 
 def load_config(name: str, root: Path = HERE) -> dict:
@@ -37,14 +36,18 @@ def load_config(name: str, root: Path = HERE) -> dict:
     return json.loads(path.read_text())
 
 
-def arch_of(cfg: dict) -> Arch:
-    """The :class:`Arch` of a configuration file."""
-    if cfg["family"] != "dense":
-        raise ValueError(f"unknown family {cfg['family']!r}")
-    d, h = cfg["hidden_size"], cfg["num_attention_heads"]
-    return Arch(
-        n_layers=cfg["num_hidden_layers"], d_model=d, vocab=cfg["vocab_size"],
-        n_heads=h, n_kv_heads=cfg["num_key_value_heads"],
-        head_dim=cfg.get("head_dim", d // h), d_ff=cfg["intermediate_size"],
-        rope_theta=cfg["rope_theta"], qkv_bias=True, eps=cfg["rms_norm_eps"],
-        dtype=cfg["torch_dtype"])
+def load_family(name: str, root: Path = HERE):
+    """The module ``families/<name>.py`` under ``root``, loaded once."""
+    path = (root / "families" / f"{name}.py").resolve()
+    if path not in _FAMILIES:
+        if not path.is_file():
+            raise FileNotFoundError(f"no module for family {name!r} at {path}")
+        # one module name per file: two roots may each hold a ``dense``
+        mod_name = f"bench_family_{len(_FAMILIES)}_" + \
+            name.replace(".", "_").replace("-", "_")
+        spec = importlib.util.spec_from_file_location(mod_name, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[mod_name] = mod     # dataclasses look their module up
+        spec.loader.exec_module(mod)
+        _FAMILIES[path] = mod
+    return _FAMILIES[path]

@@ -1,5 +1,6 @@
 """Arithmetic the metric readers share: the work of the window's requests
-from the traffic (``work.py``), and device times from the trace.
+from the traffic (the family's ``window_work``), and device times from
+the trace.
 
 Every request of a window is submitted and completed inside it, so the
 window's work is the whole work of its requests: each prompt fed in
@@ -10,7 +11,6 @@ the pieces.
 from __future__ import annotations
 
 import trace_reduce as tracemod
-import work as W
 
 DECODE_PROGRAM = "_step_n"
 PREFILL_PROGRAM = "_prefill_step"
@@ -23,27 +23,16 @@ def output_tokens(run) -> int:
 
 
 def window_work(run) -> dict | None:
-    """Work of the window's requests by layer, or None where nothing was
-    traced or a request did not complete."""
+    """Work of the window's requests by layer (the family's
+    ``window_work``), or None where nothing was traced or a request did
+    not complete."""
     if run.trace is None or not run.recs or len(run.done()) < len(run.recs):
         return None
-    arch = run.cell.arch
-    chunk = run.cell.config["engine"]["prefill_chunk"]
-    att_pre, att_dec = W.Work(), W.Work()
-    tokens = head = ctx = 0
-    for r in run.recs:
-        fed = r.plen + r.max_new - 1          # positions fed to the model
-        att_pre += W.attention(arch, 0, r.plen, chunk=chunk)
-        att_dec += W.attention(arch, r.plen, fed, chunk=1)
-        tokens += fed
-        head += r.max_new                     # positions whose logits are used
-        ctx += W.ctx_sum(0, fed)
+    cell = run.cell
     dispatches = sum(c.prefill_steps + c.decode_steps for c in run.cycles)
-    return {
-        "attention": (att_pre, att_dec),
-        "gemm": W.gemm(arch, tokens, head, dispatches),
-        "model_flops": W.model_flops(arch, tokens, head, ctx),
-    }
+    return cell.family.window_work(
+        cell.arch, run.recs, cell.config["engine"]["prefill_chunk"],
+        dispatches)
 
 
 def peak(run) -> dict:

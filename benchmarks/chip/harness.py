@@ -3,16 +3,20 @@
 Everything a cell is made of is found by name under the benchmark's
 directory: ``BENCHMARK.json`` (one level up from ``benchmarks/``) names
 the cell's configuration and traffic mix; ``configs/<config>.json`` holds
-the sizes, ``traffic/<mix>.json`` the mix's parameters,
-``cells/<cell>.json`` what belongs to the pair alone (the check's limit),
-and ``metrics/<metric>.py`` one reader per metric.  Adding a cell, a mix,
-a configuration or a metric adds files and entries and edits none.
+the sizes and the engine's settings, ``families/<family>.py`` the code
+for the configuration's ``family`` (its sizes, weight table, reference and
+work; ``arch.py`` lists what it provides), ``traffic/<mix>.json`` the
+mix's parameters, ``cells/<cell>.json`` what belongs to the pair alone
+(the check's limit), and ``metrics/<metric>.py`` one reader per metric.
+Adding a cell, a mix, a configuration, a family or a metric adds files
+and entries and edits none.
 
-The window: the mix's clients submit requests for ``--seconds`` (each
-client its next request as soon as its last one completes), then submit
-no more, and the window lasts until every request it took has completed.
-So every output token of the window is one the engine returned, and
-every request is finished and can be checked.
+The window: the mix's clients submit requests (each client its next
+request as soon as its last one completes) for ``--seconds``, or, where
+the mix states ``requests``, until that many have been submitted; then
+they submit no more, and the window lasts until every request it took
+has completed.  So every output token of the window is one the engine
+returned, and every request is finished and can be checked.
 
 The engine is driven through its public surface only (``submit``,
 ``step``, ``outputs``, ``steps``, ``prefill_steps``), under the
@@ -39,7 +43,7 @@ import jax.numpy as jnp
 import reference
 import trace_reduce as tracemod
 import weights
-from arch import Arch, arch_of, load_config
+from arch import load_config, load_family
 from traffic import Traffic, load_mix
 
 HERE = Path(__file__).resolve().parent
@@ -59,7 +63,8 @@ class Cell:
     chips: int
     config_name: str
     config: dict
-    arch: Arch
+    family: object        # the module families/<family>.py
+    arch: object          # the family's Arch
     traffic_name: str
     mix: dict
     params: dict          # cells/<name>.json
@@ -76,6 +81,7 @@ def load_cell(name: str, root: Path = HERE, bench: dict | None = None) -> Cell:
         raise KeyError(f"no workload {name!r} in BENCHMARK.json")
     w = cells[name]
     config = load_config(w["config"], root)
+    family = load_family(config["family"], root)
     params = json.loads((root / "cells" / f"{name}.json").read_text())
     e2e = [m for m in bench["end_to_end"]
            if name in m.get("workloads", [name])]
@@ -83,9 +89,9 @@ def load_cell(name: str, root: Path = HERE, bench: dict | None = None) -> Cell:
     per_layer = [m for m in bench["per_layer"]
                  if (name in m["workloads"] if "workloads" in m
                      else m["moves"] in names)]
-    return Cell(root, name, int(w["chips"]), w["config"], config, arch_of(config),
-                w["traffic"], load_mix(w["traffic"], root), params, e2e,
-                per_layer)
+    return Cell(root, name, int(w["chips"]), w["config"], config, family,
+                family.arch_of(config), w["traffic"],
+                load_mix(w["traffic"], root), params, e2e, per_layer)
 
 
 def load_reader(metric: str, root: Path = HERE):
@@ -111,12 +117,7 @@ def build_program(cell: Cell):
     from repro.models.model import build_model
 
     cfg = get_arch(cell.config["arch"])
-    a = cell.arch
-    want = {"n_layers": a.n_layers, "d_model": a.d_model,
-            "vocab_size": a.vocab, "dtype": a.dtype, "n_heads": a.n_heads,
-            "n_kv_heads": a.n_kv_heads, "head_dim_": a.head_dim,
-            "d_ff": a.d_ff, "rope_theta": a.rope_theta,
-            "qkv_bias": a.qkv_bias, "tie_embeddings": True}
+    want = cell.family.program_sizes(cell.arch)
     got = {k: getattr(cfg, k) for k in want}
     if got != want:
         raise ValueError(f"the program's {cfg.name} differs from "
@@ -127,7 +128,7 @@ def build_program(cell: Cell):
 def make_weights(cell: Cell, model, seed: int):
     """The benchmark's weights, checked leaf by leaf against the shapes
     and dtypes the program's own initializer gives."""
-    params = weights.make(cell.arch, seed)
+    params = weights.make(cell.family.shapes, cell.arch, seed)
     want = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
     got = {p: (x.shape, x.dtype) for p, x in weights.flat(params).items()}
     ref = {p: (x.shape, x.dtype) for p, x in weights.flat(want).items()}
@@ -137,14 +138,32 @@ def make_weights(cell: Cell, model, seed: int):
     return jax.block_until_ready(params)
 
 
-def build_engine(cell: Cell, model, params):
-    from repro.serving import CacheConfig, EngineConfig, ServingEngine
+def engine_configs(engine: dict):
+    """``(CacheConfig, EngineConfig)`` of a configuration's ``engine``
+    object: each key sets the field of that name (``kv_layout`` sets
+    ``layout``; ``note`` is prose), and any other key stops the run."""
+    from dataclasses import fields
 
-    e = cell.config["engine"]
-    cache = CacheConfig(layout=e["kv_layout"], page_size=e["page_size"],
-                        kv_dtype=e["kv_dtype"])
-    config = EngineConfig(prefill_chunk=e["prefill_chunk"],
-                          steps_per_sync=e["steps_per_sync"])
+    from repro.serving import CacheConfig, EngineConfig
+
+    kw: dict = {CacheConfig: {}, EngineConfig: {}}
+    for key, value in engine.items():
+        if key == "note":
+            continue
+        name = "layout" if key == "kv_layout" else key
+        cls = next((c for c in kw if name in {f.name for f in fields(c)}),
+                   None)
+        if cls is None:
+            raise ValueError(f"unknown engine key {key!r}: no CacheConfig "
+                             f"or EngineConfig field of that name")
+        kw[cls][name] = value
+    return CacheConfig(**kw[CacheConfig]), EngineConfig(**kw[EngineConfig])
+
+
+def build_engine(cell: Cell, model, params):
+    from repro.serving import ServingEngine
+
+    cache, config = engine_configs(cell.config["engine"])
     return ServingEngine(model, params, batch=cell.mix["rows"],
                          max_len=cell.mix["max_len"], cache=cache,
                          config=config)
@@ -224,12 +243,17 @@ class Driver:
             rec.t_done, rec.out = t1, eng.outputs[eid]
             self.in_flight.discard(eid)
 
-    def closed(self, clients: int, until: float, max_cycles: int) -> None:
-        """Keep ``clients`` requests in flight until ``until``, then run
+    def closed(self, clients: int, until: float, max_cycles: int,
+               requests: int | None = None) -> None:
+        """Keep ``clients`` requests in flight until ``until``, or, with
+        ``requests``, until that many have been submitted; then run
         cycles until none is, or ``max_cycles`` more have run (a request
         that never completes is then left for the check to count)."""
-        while time.perf_counter() < until:
-            while len(self.in_flight) < clients:
+        def room() -> bool:
+            return requests is None or len(self.run.recs) < requests
+
+        while (time.perf_counter() < until if requests is None else room()):
+            while len(self.in_flight) < clients and room():
                 self.submit()
             self.cycle()
         for _ in range(max_cycles):
@@ -319,9 +343,13 @@ def serve(cell: Cell, seed: int, seconds: float, *, trace_dir: Path | None,
             start_trace(trace_dir)
         with CompileCount() as compiles:
             drv.closed(cell.mix["clients"], run.t_start + seconds,
-                       drain_cycles(cell))
+                       drain_cycles(cell), cell.mix.get("requests"))
         run.t_end = time.perf_counter()
         run.notes["compiles_in_window"] = compiles.n
+        # the K/V the window's requests held at the most, against the pool
+        st = eng.stats()
+        run.notes["kv_live_peak"] = {k: int(st[k]) for k in (
+            "kv_pages_peak", "kv_pages", "kv_resident_bytes_peak") if k in st}
         if trace_dir is not None:
             jax.profiler.stop_trace()
         run.notes["setup_phases_s"] = {
@@ -358,7 +386,7 @@ def sample(run: Run) -> list:
     return picked
 
 
-def gaps(arch: Arch, params, recs: list, max_len: int, out_hi: int,
+def gaps(family, arch, params, recs: list, max_len: int, out_hi: int,
          quantize: str = "") -> list:
     """Per sampled request, the reference's gaps at its served tokens;
     with ``quantize``, the gaps of the tokens the lower-precision
@@ -371,11 +399,11 @@ def gaps(arch: Arch, params, recs: list, max_len: int, out_hi: int,
         feed[:len(toks)] = toks
         pos = np.full((out_hi,), r.plen + r.max_new - 2, np.int32)
         pos[:r.max_new] = np.arange(r.plen - 1, r.plen + r.max_new - 1)
-        want = reference.logits(arch, params, jnp.asarray(feed),
-                                jnp.asarray(pos))[:r.max_new]
+        want = family.logits(arch, params, jnp.asarray(feed),
+                             jnp.asarray(pos))[:r.max_new]
         if quantize:
-            got = reference.logits(arch, params, jnp.asarray(feed),
-                                   jnp.asarray(pos), quantize)[:r.max_new]
+            got = family.logits(arch, params, jnp.asarray(feed),
+                                jnp.asarray(pos), quantize)[:r.max_new]
             chosen = jnp.argmax(got, axis=1).astype(jnp.int32)
         else:
             chosen = jnp.asarray(r.out.astype(np.int32))
@@ -392,8 +420,8 @@ def check(run: Run, params, limit: float, quantize: str = "") -> dict:
     unfinished = len(run.recs) - len(run.done())
     picked = sample(run)
     with jax.profiler.TraceAnnotation("bench.check"):
-        g = gaps(cell.arch, params, picked, cell.mix["max_len"],
-                 cell.mix["output"]["hi"], quantize)
+        g = gaps(cell.family, cell.arch, params, picked,
+                 cell.mix["max_len"], cell.mix["output"]["hi"], quantize)
     worst = float(max((x.max() for x in g), default=float("inf")))
     checks = {
         "max_gap": {"value": worst, "limit": limit},
@@ -458,6 +486,7 @@ def _log_summary(run: Run, res: dict, log) -> None:
         f"{sum(c.prefill_steps for c in cyc)} prefill and "
         f"{sum(c.decode_steps for c in cyc)} decode steps")
     log(f"set-up phases (s): {run.notes['setup_phases_s']}; compiles in "
-        f"the window: {run.notes['compiles_in_window']}")
+        f"the window: {run.notes['compiles_in_window']}; K/V at peak: "
+        f"{run.notes['kv_live_peak']}")
     log(f"check: {res['checked']} served tokens of {res['requests_checked']} "
         f"requests against the reference, {res['argmax']} of them its argmax")

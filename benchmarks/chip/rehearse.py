@@ -42,7 +42,8 @@ def rehearse(name: str, one_chip) -> dict:
 
     cell = harness.load_cell(name)
     model = harness.build_program(cell)
-    params = jax.eval_shape(lambda: weights.make(cell.arch, 0))
+    params = jax.eval_shape(
+        lambda: weights.make(cell.family.shapes, cell.arch, 0))
     shape_model = dataclasses.replace(
         model, init_decode_state=lambda *a, **k: jax.eval_shape(
             lambda: model.init_decode_state(*a, **k)))

@@ -1,6 +1,7 @@
 """The whole run on the CPU at smoke size: sound runs are correct, the
 lower-precision control and planted faults are not (CPU, Pallas in
 interpret mode)."""
+import json
 import time
 
 import tiny
@@ -13,16 +14,29 @@ import calibrate
 import derive
 import harness
 
-CELLS = {"dense.closed": ("tiny-dense", "tiny-closed")}
+CELLS = {"dense.closed": ("tiny-dense", "tiny-closed"),
+         "dense.chat": ("tiny-dense", "tiny-chat"),
+         "twin.closed": ("tiny-twin", "tiny-closed")}
 # float32 program against the float32 reference reads 0 at this size;
 # the bfloat16 control read 5e-4 to 2.4e-3 on seeds 1 and 2
 LIMIT = 1e-4
 
 
+def add_twin_family(root):
+    """A family added as files alone: ``families/twin.py``, a dense twin
+    under another name, and a configuration that names it."""
+    (root / "families" / "twin.py").write_text(
+        (tiny.CHIP / "families" / "dense.py").read_text())
+    cfg = {**tiny.CONFIGS["tiny-dense"], "family": "twin"}
+    (root / "configs" / "tiny-twin.json").write_text(json.dumps(cfg))
+
+
 @pytest.fixture(scope="module")
 def root(tmp_path_factory):
-    return tiny.make_root(tmp_path_factory.mktemp("bench"),
-                          {"dense.closed": {"max_gap_limit": LIMIT}})
+    root = tiny.make_root(tmp_path_factory.mktemp("bench"),
+                          {n: {"max_gap_limit": LIMIT} for n in CELLS})
+    add_twin_family(root)
+    return root
 
 
 def _cell(root, name):
@@ -52,6 +66,18 @@ def test_every_request_of_the_window_completes(root, name):
     assert run.recs and len(run.done()) == len(run.recs)
     assert all(run.t_start < r.t_done <= run.t_end for r in run.recs)
     assert derive.output_tokens(run) == sum(r.max_new for r in run.recs)
+    # a mix that states its requests submits exactly that many
+    if "requests" in run.cell.mix:
+        assert len(run.recs) == run.cell.mix["requests"]
+
+
+def test_a_family_added_as_files_resolves_to_its_own_module(root):
+    """``twin.closed`` serves above through ``families/twin.py`` alone."""
+    from pathlib import Path
+    cell = _cell(root, "twin.closed")
+    assert Path(cell.family.__file__) == \
+        (root / "families" / "twin.py").resolve()
+    assert cell.family is not _cell(root, "dense.closed").family
 
 
 @pytest.mark.parametrize("name", sorted(CELLS))

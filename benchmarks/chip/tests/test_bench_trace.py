@@ -5,7 +5,9 @@ import pytest
 
 import trace_reduce as T
 import work as W
-from arch import arch_of, load_config
+from arch import load_config, load_family
+
+D = load_family("dense")
 
 
 def _trace():
@@ -71,7 +73,7 @@ def test_union_merges_touching_intervals():
 
 @pytest.fixture(scope="module")
 def qwen():
-    return arch_of(load_config("qwen2.5-3b"))
+    return D.arch_of(load_config("qwen2.5-3b"))
 
 
 def test_matmul_flops_per_token_from_param_count(qwen):
@@ -80,23 +82,23 @@ def test_matmul_flops_per_token_from_param_count(qwen):
     # the program's analytic count less the embedding and the norms
     want = cfg.param_count() - cfg.vocab_size * cfg.d_model \
         - cfg.n_layers * 2 * cfg.d_model
-    assert W.matmul_params(qwen) == want
-    assert W.gemm(qwen, 1, 0, 0).flops == 2 * want
+    assert D.matmul_params(qwen) == want
+    assert D.gemm(qwen, 1, 0, 0).flops == 2 * want
 
 
 def test_live_kv_bytes(qwen):
     # 36 layers x K and V x 2 heads x 128 x bf16
-    assert W.kv_bytes_per_token(qwen) == 36_864
-    one = W.attention(qwen, 2999, 3000, chunk=1)   # one token at context 3000
+    assert D.kv_bytes_per_token(qwen) == 36_864
+    one = D.attention(qwen, 2999, 3000, chunk=1)   # one token at context 3000
     assert one.bytes == 3000 * 36_864
     assert one.flops == 4 * 3000 * 16 * 128 * 36
-    chunked = W.attention(qwen, 0, 256, chunk=128)
+    chunked = D.attention(qwen, 0, 256, chunk=128)
     assert chunked.bytes == (128 + 256) * 36_864
     assert chunked.flops == 4 * (256 * 257 // 2) * 16 * 128 * 36
 
 
 def test_roofline_bound_names_the_limit(qwen):
-    t, bound = W.attention(qwen, 2999, 3000, chunk=1).bound_s(197e12, 819e9)
+    t, bound = D.attention(qwen, 2999, 3000, chunk=1).bound_s(197e12, 819e9)
     assert bound == "memory" and t == pytest.approx(3000 * 36_864 / 819e9)
 
 
@@ -105,7 +107,7 @@ def test_window_work_is_the_requests_whole_work(qwen):
     from types import SimpleNamespace as NS
     recs = [NS(plen=300, max_new=5, out=[0] * 5),
             NS(plen=128, max_new=1, out=[0])]
-    cell = NS(arch=qwen, config={"engine": {"prefill_chunk": 128}})
+    cell = NS(arch=qwen, family=D, config={"engine": {"prefill_chunk": 128}})
     run = NS(trace=T.Trace(), recs=recs, cell=cell,
              cycles=[NS(prefill_steps=3, decode_steps=8)],
              done=lambda: recs)
@@ -116,8 +118,8 @@ def test_window_work_is_the_requests_whole_work(qwen):
     # generated tokens fed one at a time: positions 300..303
     assert dec.bytes == (301 + 302 + 303 + 304) * 36_864
     fed = 304 + 128
-    assert w["gemm"].flops == 2 * (fed * W.matmul_params(qwen)
-                                   + 6 * W.head_params(qwen))
+    assert w["gemm"].flops == 2 * (fed * D.matmul_params(qwen)
+                                   + 6 * D.head_params(qwen))
     assert w["model_flops"] == w["gemm"].flops + 4 * (
         W.ctx_sum(0, 304) + W.ctx_sum(0, 128)) * 16 * 128 * 36
     # a request that never completed leaves the work unknown
