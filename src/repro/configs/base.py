@@ -64,6 +64,8 @@ class ArchConfig:
     n_vision_tokens: int = 0
     # norm / misc
     norm: str = "rmsnorm"
+    norm_eps: float = 1e-6         # every RMSNorm: block, gated, final
+    residual_f32: bool = False     # ssm: carry the residual stream in f32
     tie_embeddings: bool = False
     dtype: str = "bfloat16"
     sub_quadratic: bool = False    # eligible for long_500k
@@ -83,6 +85,10 @@ class ArchConfig:
     @property
     def ssm_heads(self) -> int:
         return self.d_inner // self.ssm_head_dim
+
+    @property
+    def conv_dim(self) -> int:      # channels of the causal conv: x, B, C
+        return self.d_inner + 2 * self.ssm_state
 
     @property
     def is_attention_free(self) -> bool:
@@ -135,17 +141,18 @@ class ArchConfig:
         emb = v * d * (1 if self.tie_embeddings else 2)
         per_attn = d * (self.n_heads * hd) + 2 * d * (self.n_kv_heads * hd) \
             + (self.n_heads * hd) * d
-        if self.family == "ssm":
-            # mamba2 block: in_proj (d -> 2*d_inner + 2*G*N + H), conv, out
-            di, n, h = self.d_inner, self.ssm_state, self.ssm_heads
-            per_layer = d * (2 * di + 2 * n + h) + di * self.ssm_conv + di * d \
-                + 2 * d  # norms
-            return emb + self.n_layers * per_layer
-        if self.family == "hybrid":
-            di, n, h = self.d_inner, self.ssm_state, self.ssm_heads
-            per_m = d * (2 * di + 2 * n + h) + di * self.ssm_conv + di * d + 2 * d
-            shared_attn = per_attn + 3 * d * self.d_ff + 2 * d
-            return emb + self.n_layers * per_m + shared_attn
+        if self.family in ("ssm", "hybrid"):
+            # mamba2 block: in_proj (d -> 2*d_inner + 2*G*N + H), conv
+            # weight and bias over x|B|C, A_log/D/dt_bias, out_proj, the
+            # block norm and the gated norm
+            di, n, h, cd = self.d_inner, self.ssm_state, self.ssm_heads, \
+                self.conv_dim
+            per_m = d * (2 * di + 2 * n + h) + cd * (self.ssm_conv + 1) \
+                + 3 * h + di * d + d + di
+            total = emb + d + self.n_layers * per_m     # d: the final norm
+            if self.family == "hybrid":
+                total += per_attn + 3 * d * self.d_ff + 2 * d
+            return total
         per_mlp = 3 * d * self.d_ff
         if self.n_experts:
             per_mlp = self.n_experts * 3 * d * self.d_ff + d * self.n_experts

@@ -143,6 +143,7 @@ _RULES = [
     (r"w_in", ("data", "model")),
     (r"w_out", ("model", "data")),
     (r"conv_w", (None, "model")),
+    (r"conv_b", ("model",)),
     # norms / scalars / small vectors: replicate
     (r"ln|gate|a_log|d_skip|dt_bias|\bb\b", None),
 ]

@@ -82,6 +82,12 @@ def _ssd_kernel(
         hf_ref[0, 0] = state_ref[...].astype(jnp.float32)
 
 
+def _kernel_name(tuning_op: str, s: int) -> str:
+    if tuning_op == "ssd_scan":
+        return "repro_ssd_scan"
+    return "repro_ssd_decode" if s == 1 else "repro_ssd_prefill_chunk"
+
+
 @functools.partial(
     jax.jit, static_argnames=("chunk", "interpret", "tuning_op")
 )
@@ -102,7 +108,11 @@ def ssd_scan_pallas(
     ``tuning_op`` names the tuning-table entry the chunk size resolves
     from: the training path tunes as ``"ssd_scan"``, the serving path
     (``ops.ssd_prefill_chunk``) as ``"ssd_prefill_chunk"`` — so serving's
-    knob is never overridden by a training setting."""
+    knob is never overridden by a training setting.  It names the kernel
+    too, by phase, so a device trace splits SSD time as it splits paged
+    attention's: ``repro_ssd_scan`` (training), ``repro_ssd_decode`` (a
+    serving call of one token per row), ``repro_ssd_prefill_chunk``
+    (a serving chunk)."""
     if interpret is None:
         interpret = interpret_default()
     b, s, h, p = x.shape
@@ -167,7 +177,7 @@ def ssd_scan_pallas(
         compiler_params=plc.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
-        name="repro_ssd_scan",
+        name=_kernel_name(tuning_op, s),
     )(
         x.transpose(0, 2, 1, 3),
         col(dt_h),

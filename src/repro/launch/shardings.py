@@ -143,9 +143,9 @@ def decode_state_shardings(
                 axes[2] = None
             return NamedSharding(mesh, S.pspec(axes))
         if s == "conv":
-            # (L, B, K-1, di)
+            # (L, B, K-1, conv_dim)
             axes = [None, "data" if wide_batch else None, None,
-                    "model" if cfg.d_inner % 16 == 0 else None]
+                    "model" if cfg.conv_dim % 16 == 0 else None]
             return NamedSharding(mesh, S.pspec(axes))
         return NamedSharding(mesh, P())
 

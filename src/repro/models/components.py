@@ -33,7 +33,7 @@ def dense(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None):
 
 
 def norm(cfg: ArchConfig, w: jax.Array, x: jax.Array) -> jax.Array:
-    return ops.rmsnorm(x, w)
+    return ops.rmsnorm(x, w, cfg.norm_eps)
 
 
 # ---------------------------------------------------------------------------
@@ -295,14 +295,16 @@ def moe_block(cfg: ArchConfig, p: Params, x: jax.Array) -> jax.Array:
 
 def init_mamba(cfg: ArchConfig, rng) -> Params:
     d, di = cfg.d_model, cfg.d_inner
-    n, h = cfg.ssm_state, cfg.ssm_heads
+    n, h, cd = cfg.ssm_state, cfg.ssm_heads, cfg.conv_dim
     ks = jax.random.split(rng, 4)
     dt = cfg.dtype_()
     s = 1.0 / np.sqrt(d)
     return {
         # in_proj emits [z (di), x (di), B (n), C (n), dt (h)]
         "w_in": (jax.random.normal(ks[0], (d, 2 * di + 2 * n + h)) * s).astype(dt),
-        "conv_w": (jax.random.normal(ks[1], (cfg.ssm_conv, di)) * 0.1).astype(dt),
+        # depthwise causal conv over x|B|C, with a bias
+        "conv_w": (jax.random.normal(ks[1], (cfg.ssm_conv, cd)) * 0.1).astype(dt),
+        "conv_b": jnp.zeros((cd,), dt),
         "a_log": jnp.zeros((h,), jnp.float32),
         "d_skip": jnp.ones((h,), jnp.float32),
         "dt_bias": jnp.zeros((h,), jnp.float32),
@@ -312,65 +314,75 @@ def init_mamba(cfg: ArchConfig, rng) -> Params:
     }
 
 
-def _causal_conv(x: jax.Array, w: jax.Array) -> jax.Array:
-    """Depthwise causal conv1d. x: (B,S,di), w: (K,di)."""
-    k = w.shape[0]
-    xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
-    out = sum(
-        xp[:, i : i + x.shape[1]] * w[i][None, None, :] for i in range(k)
-    )
-    return out
-
-
 def _split_mamba_proj(cfg: ArchConfig, zxbcdt: jax.Array):
-    di, n, h = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
-    z = zxbcdt[..., :di]
-    xs = zxbcdt[..., di : 2 * di]
-    b_ = zxbcdt[..., 2 * di : 2 * di + n]
-    c_ = zxbcdt[..., 2 * di + n : 2 * di + 2 * n]
-    dt = zxbcdt[..., 2 * di + 2 * n :]
-    return z, xs, b_, c_, dt
+    """``z``, the conv's input ``xBC`` and ``dt`` of the in-projection."""
+    di = cfg.d_inner
+    return (zxbcdt[..., :di], zxbcdt[..., di : di + cfg.conv_dim],
+            zxbcdt[..., di + cfg.conv_dim :])
+
+
+def _conv_silu(cfg: ArchConfig, p: Params, win: jax.Array, c: int):
+    """Causal depthwise conv of the last ``c`` positions of ``win`` (B,
+    K-1+c, conv_dim), each reading its K-1 predecessors, with the bias,
+    then SiLU (in f32); split into x (B, c, di), B and C (B, c, n) in the
+    served dtype."""
+    k, di, n = cfg.ssm_conv, cfg.d_inner, cfg.ssm_state
+    w = p["conv_w"].astype(jnp.float32)
+    out = p["conv_b"].astype(jnp.float32) + sum(
+        win[:, i : i + c].astype(jnp.float32) * w[i] for i in range(k)
+    )
+    xbc = jax.nn.silu(out).astype(cfg.dtype_())
+    return xbc[..., :di], xbc[..., di : di + n], xbc[..., di + n :]
+
+
+def _mamba_out(cfg: ArchConfig, p: Params, x, y, xs, z):
+    """D skip, gate, gated RMSNorm (in f32), out-projection, residual.
+    ``y``/``xs``: (B, S, H, P); ``z``: (B, S, di); ``x``: the residual."""
+    b, s = y.shape[:2]
+    y = y + xs * p["d_skip"][None, None, :, None]
+    y = y.reshape(b, s, cfg.d_inner) * jax.nn.silu(z.astype(jnp.float32))
+    y = ops.rmsnorm(y, p["ln_inner"], cfg.norm_eps).astype(cfg.dtype_())
+    return x + dense(y, p["w_out"])
 
 
 def mamba_block(cfg: ArchConfig, p: Params, x: jax.Array) -> jax.Array:
+    """Mamba-2 block over whole sequences (training): ``x`` (B, S, d) is
+    the residual, in f32 where ``cfg.residual_f32``."""
     b, s, d = x.shape
-    di, n, h, hd = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
-    xn = norm(cfg, p["ln"], x)
-    z, xs, b_, c_, dt = _split_mamba_proj(cfg, dense(xn, p["w_in"]))
-    xs = _causal_conv(xs, p["conv_w"])
-    xs = jax.nn.silu(xs)
+    h, hd, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
+    xn = norm(cfg, p["ln"], x).astype(cfg.dtype_())
+    z, xbc, dt = _split_mamba_proj(cfg, dense(xn, p["w_in"]))
+    win = jnp.pad(xbc, ((0, 0), (cfg.ssm_conv - 1, 0), (0, 0)))
+    xs, b_, c_ = _conv_silu(cfg, p, win, s)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
     a = -jnp.exp(p["a_log"])
+    xs = xs.reshape(b, s, h, hd)
     y = ops.ssd_scan(
-        xs.reshape(b, s, h, hd),
-        dt,
-        a,
-        b_.reshape(b, s, 1, n),
-        c_.reshape(b, s, 1, n),
+        xs, dt, a, b_.reshape(b, s, 1, n), c_.reshape(b, s, 1, n),
         chunk=cfg.ssm_chunk,
     )
-    y = y + xs.reshape(b, s, h, hd) * p["d_skip"][None, None, :, None]
-    y = (y.reshape(b, s, di) * jax.nn.silu(z)).astype(x.dtype)
-    y = ops.rmsnorm(y, p["ln_inner"])
-    return x + dense(y, p["w_out"])
+    return _mamba_out(cfg, p, x, y, xs, z)
 
 
 def mamba_prefill_block(
     cfg: ArchConfig, p: Params,
     x: jax.Array,              # (B, C, d): C tokens per sequence
     ssm_state: jax.Array,      # (B, H, P, N) f32: carried recurrent state
-    conv_state: jax.Array,     # (B, K-1, di): carried conv window
+    conv_state: jax.Array,     # (B, K-1, conv_dim): carried conv window
     valid: jax.Array,          # (B, C) bool: prefix mask of real tokens
 ):
     """Chunked Mamba-2 block with carried recurrent state — the single
     source of the recurrent families' serving-time math.
 
     A chunk of C tokens runs as B*C-row projections, one chunked causal
-    conv against the carried (K-1)-deep window, and one SSD scan seeded
-    with the carried state (``ops.ssd_prefill_chunk``) — instead of C
-    sequential single-token dispatches.  ``mamba_decode_block`` is the
+    conv of x|B|C against the carried (K-1)-deep window, and one SSD scan
+    seeded with the carried state (``ops.ssd_prefill_chunk``) — instead of
+    C sequential single-token dispatches.  ``mamba_decode_block`` is the
     C=1 case of this function, so decode and prefill share one
     accumulation order rather than two hand-synchronized recurrences.
+    ``x`` is the residual stream (f32 where ``cfg.residual_f32``): the
+    block normalizes it, projects in the served dtype and adds its output
+    back.
 
     Per-row widths ride on the ``valid`` prefix mask: a padding position's
     ``dt`` is zeroed (exp(0) decay, zero input — an algebraic no-op on the
@@ -380,40 +392,34 @@ def mamba_prefill_block(
     Outputs at padding positions are finite garbage the caller discards.
     """
     b, c, d = x.shape
-    di, n, h, hd = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
+    h, hd, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
     k = cfg.ssm_conv
-    xn = norm(cfg, p["ln"], x)
-    z, xs, b_, c_, dt = _split_mamba_proj(cfg, dense(xn, p["w_in"]))
+    xn = norm(cfg, p["ln"], x).astype(cfg.dtype_())
+    z, xbc, dt = _split_mamba_proj(cfg, dense(xn, p["w_in"]))
     # chunked causal conv against the carried window: position i of the
     # chunk reads raw inputs i-K+1..i, reaching into the carry for i < K-1
-    win = jnp.concatenate([conv_state, xs], axis=1)        # (B, K-1+C, di)
-    xs = sum(
-        win[:, i : i + c] * p["conv_w"][i][None, None, :] for i in range(k)
-    )
+    win = jnp.concatenate([conv_state, xbc], axis=1)   # (B, K-1+C, conv_dim)
+    xs, b_, c_ = _conv_silu(cfg, p, win, c)
     # new conv window: the last K-1 inputs up to each row's width (width 0
     # gathers win[:, :K-1] — the old carry, verbatim)
     width = jnp.sum(valid, axis=1, dtype=jnp.int32)        # (B,)
     gidx = width[:, None] + jnp.arange(k - 1, dtype=jnp.int32)[None, :]
     conv_state = jnp.take_along_axis(win, gidx[:, :, None], axis=1)
-    xs = jax.nn.silu(xs)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
     dt = jnp.where(valid[:, :, None], dt, 0.0)             # padding: no-op
     a = -jnp.exp(p["a_log"])
+    xs = xs.reshape(b, c, h, hd)
     y, ssm_state = ops.ssd_prefill_chunk(
-        xs.reshape(b, c, h, hd), dt, a,
-        b_.reshape(b, c, 1, n), c_.reshape(b, c, 1, n),
+        xs, dt, a, b_.reshape(b, c, 1, n), c_.reshape(b, c, 1, n),
         ssm_state, chunk=cfg.ssm_chunk,
     )
-    y = y + xs.reshape(b, c, h, hd) * p["d_skip"][None, None, :, None]
-    y = (y.reshape(b, c, di) * jax.nn.silu(z)).astype(x.dtype)
-    y = ops.rmsnorm(y, p["ln_inner"])
-    return x + dense(y, p["w_out"]), ssm_state, conv_state
+    return _mamba_out(cfg, p, x, y, xs, z), ssm_state, conv_state
 
 
 def mamba_decode_block(
     cfg: ArchConfig, p: Params, x: jax.Array,
     ssm_state: jax.Array,      # (B, H, P, N)
-    conv_state: jax.Array,     # (B, K-1, di)
+    conv_state: jax.Array,     # (B, K-1, conv_dim)
     valid: Optional[jax.Array] = None,   # (B, 1) bool; None = all rows live
 ):
     """Single-token decode — the C=1 case of ``mamba_prefill_block``.

@@ -109,6 +109,13 @@ def init_params(cfg: ArchConfig, rng) -> Dict[str, Any]:
 # Forward (train / prefill)
 # ---------------------------------------------------------------------------
 
+def _residual_dtype(cfg: ArchConfig):
+    """The residual stream's dtype: float32 where the model keeps it so
+    (``residual_f32``, the ssm family), else the served dtype.  Blocks
+    normalize it and project in the served dtype."""
+    return jnp.float32 if cfg.residual_f32 else cfg.dtype_()
+
+
 def _layer_apply(cfg: ArchConfig, p, x, positions):
     if "mamba" in p:
         return C.mamba_block(cfg, p["mamba"], x)
@@ -128,7 +135,7 @@ def forward(
     vision: Optional[jax.Array] = None,  # (B, P, d) VLM patch embeddings
     remat: bool = True,
 ) -> jax.Array:
-    x = params["embed"][tokens].astype(cfg.dtype_())
+    x = params["embed"][tokens].astype(_residual_dtype(cfg))
     x = shard(x, ("data", "sp", None))
     s = tokens.shape[1]
     positions = jnp.arange(s)
@@ -185,7 +192,7 @@ def forward(
 
 def lm_logits(cfg: ArchConfig, params, h: jax.Array) -> jax.Array:
     w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    return C.dense(h, w)
+    return C.dense(h.astype(cfg.dtype_()), w)
 
 
 def _xent(logits: jax.Array, targets: jax.Array) -> jax.Array:
@@ -362,7 +369,7 @@ def init_decode_state(
                  cfg.ssm_state), jnp.float32,
             ),
             "snap_conv": jnp.zeros(
-                (n_slots, cfg.n_layers, cfg.ssm_conv - 1, cfg.d_inner), dt
+                (n_slots, cfg.n_layers, cfg.ssm_conv - 1, cfg.conv_dim), dt
             ),
             "snap_table": P.init_block_table(batch, n_bound),
             "snap_free": ps.free,
@@ -395,7 +402,7 @@ def init_decode_state(
             jnp.float32,
         )
         state["conv"] = jnp.zeros(
-            (cfg.n_layers, batch, cfg.ssm_conv - 1, cfg.d_inner), dt
+            (cfg.n_layers, batch, cfg.ssm_conv - 1, cfg.conv_dim), dt
         )
         if snapshots:
             state.update(snap_store())
@@ -406,7 +413,7 @@ def init_decode_state(
             jnp.float32,
         )
         state["conv"] = jnp.zeros(
-            (cfg.n_layers, batch, cfg.ssm_conv - 1, cfg.d_inner), dt
+            (cfg.n_layers, batch, cfg.ssm_conv - 1, cfg.conv_dim), dt
         )
         if snapshots:
             state.update(snap_store(host=host_spill and layout == "paged"))
@@ -764,7 +771,7 @@ def decode_step(
     # lm_head) label every op of the step in the compiled program's op
     # metadata, so a device trace can attribute time to each part
     with jax.named_scope("embed"):
-        x = params["embed"][token].astype(cfg.dtype_())   # (B, d)
+        x = params["embed"][token].astype(_residual_dtype(cfg))  # (B, d)
     # paged layout uses absolute positions (window masking in attention);
     # the contiguous layout ring-indexes sliding-window caches
     idx = pos if paged else _cache_index(cfg, pos)
@@ -958,7 +965,7 @@ def decode_step(
         raise ValueError(fam)
 
     with jax.named_scope("lm_head"):
-        x = C.norm(cfg, params["ln_f"], x)
+        x = C.norm(cfg, params["ln_f"], x).astype(cfg.dtype_())
         w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
         logits = C.dense(x, w)
     with jax.named_scope("kv_pool"):
@@ -1036,7 +1043,7 @@ def prefill_chunk(
     )
     # named scopes as in decode_step
     with jax.named_scope("embed"):
-        x = params["embed"][toks].astype(cfg.dtype_())     # (B, C, d)
+        x = params["embed"][toks].astype(_residual_dtype(cfg))  # (B, C, d)
         offs = jnp.arange(c, dtype=jnp.int32)[None, :]
         posmat = pos[:, None] + offs                       # (B, C) positions
         valid = active[:, None] & (offs < width[:, None])  # (B, C) real
@@ -1189,13 +1196,13 @@ def prefill_chunk(
         if logits_all:
             # verifier path: every chunk position's logits (B, C, V);
             # padding positions carry garbage the caller masks by width
-            h = C.norm(cfg, params["ln_f"], x)
+            h = C.norm(cfg, params["ln_f"], x).astype(cfg.dtype_())
             logits = C.dense(h, w)
         else:
             last = jnp.take_along_axis(
                 x, (width - 1)[:, None, None], axis=1
             )[:, 0]
-            h = C.norm(cfg, params["ln_f"], last)
+            h = C.norm(cfg, params["ln_f"], last).astype(cfg.dtype_())
             logits = C.dense(h, w)
     with jax.named_scope("kv_pool"):
         state = {**state, "pos": pos + jnp.where(active, width, 0)}

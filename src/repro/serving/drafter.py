@@ -134,7 +134,7 @@ class HybridSSMDrafter:
                  cfg.ssm_state), jnp.float32,
             ),
             "drf_conv": jnp.zeros(
-                (cfg.n_layers, batch, cfg.ssm_conv - 1, cfg.d_inner),
+                (cfg.n_layers, batch, cfg.ssm_conv - 1, cfg.conv_dim),
                 cfg.dtype_(),
             ),
             "drf_pos": jnp.zeros((batch,), jnp.int32),

@@ -554,6 +554,13 @@ class ServingEngine:
                 for key in ("k", "v", "xk", "xv") if key in self._mstate
             )
 
+        # recurrent state is fixed per row: its bytes are shape arithmetic
+        self._recurrent_bytes = sum(
+            self._mstate[key].dtype.itemsize
+            * int(np.prod(self._mstate[key].shape))
+            for key in ("ssm", "conv") if key in self._mstate
+        )
+
         self._slots = init_slots(batch, max_len)
         # per-request key *data* is derived host-side from (engine seed,
         # req_id) — no device round-trip on the admission path, and the
@@ -1634,6 +1641,9 @@ class ServingEngine:
             out["kv_resident_bytes_peak"] = float(
                 self.kv_resident_bytes(peak=True)
             )
+        if self._recurrent:
+            # device bytes of the live SSM and conv state of every row
+            out["recurrent_state_bytes"] = float(self._recurrent_bytes)
         if self._snap:
             out["snap_slots"] = float(self.n_snap_slots)
             out["snap_slots_peak"] = float(self.peak_snaps_in_use)

@@ -84,9 +84,10 @@ page-boundary snapshot store whose contract deliberately mirrors the
 block table's (``lm.init_decode_state(snapshots=True)`` builds it):
 
   * snapshot pools ``snap_ssm (n_slots, layers, H, P, N)`` f32 and
-    ``snap_conv (n_slots, layers, K-1, d_inner)`` — one slot holds a
-    row's *full-depth* SSM + conv state captured exactly at a page
-    boundary (after feeding ``(j+1) * page_size`` tokens).
+    ``snap_conv (n_slots, layers, K-1, d_inner + 2*N)`` (the conv window
+    over x|B|C) — one slot holds a row's *full-depth* SSM + conv state
+    captured exactly at a page boundary (after feeding ``(j+1) *
+    page_size`` tokens).
   * slot table  ``snap_table (B, max_boundaries)`` int32 — column ``j``
     maps the slot for boundary ``j+1``; ``-1`` = no snapshot.  Boundary
     space is block space with ``page_size == 1``: the *same* allocator

@@ -109,6 +109,20 @@ def test_gemm_plan_at_cell_shapes():
     assert program(16) <= 5_000
 
 
+@pytest.mark.parametrize("m,k,n", _CELL_SHAPES)
+def test_gemm_plan_at_cell_shapes_as_before(m, k, n, monkeypatch):
+    """The VMEM term for output lanes no multiple of 256 leaves qwen2.5-3b's
+    plans as they were: each is the plan of the former count, which took
+    two f32 blocks whatever the lanes."""
+    from repro.kernels import gemm as G
+
+    plan = G.plan_blocks(m, k, n, 2, 2)
+    monkeypatch.setattr(
+        G, "vmem_bytes", lambda bm, bn, bk, item, out_item:
+        2 * (bm * bk + bk * bn) * item + 8 * bm * bn + 2 * bm * bn * out_item)
+    assert G._least_time(m, k, n, 2, 2) == plan
+
+
 def test_autotune_sweeps_gemm_around_its_plan():
     """gemm's blocks come from its plan, not literal defaults: the sweep
     still runs, around the plan each case's baseline resolved."""

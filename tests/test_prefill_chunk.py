@@ -203,7 +203,7 @@ def test_mamba_prefill_block_matches_sequential_decode():
         with use_backend(backend):
             ssm = jnp.zeros((b, cfg.ssm_heads, cfg.ssm_head_dim,
                              cfg.ssm_state), jnp.float32)
-            conv = jnp.zeros((b, cfg.ssm_conv - 1, cfg.d_inner), jnp.float32)
+            conv = jnp.zeros((b, cfg.ssm_conv - 1, cfg.conv_dim), jnp.float32)
             s1, s2 = ssm, conv
             ys = []
             for t in range(2 * c):

@@ -6,7 +6,7 @@ breaks the (8, 128) tiling rule or a kernel that overflows VMEM fails
 here, at no chip time.  Widths are qwen2.5-3b's (d_model 2048, 16/2
 heads of 128, d_ff 11008, vocab 151936, page 16, prefill chunk 128,
 batch 8) and, for the SSD scan, mamba2-2.7b's (80 heads of 64, state
-128).  Nothing runs: a passing compile says nothing about results.
+128; batch 8 and the chat cell's 16 rows).  Nothing runs: a passing compile says nothing about results.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
@@ -34,6 +34,9 @@ B, C, D_MODEL, HQ, HKV, HD, D_FF, VOCAB = 8, 128, 2048, 16, 2, 128, 11008, 15193
 PAGE, MAX_BLOCKS = 16, 35                 # 512-token prompt + 32 new, paged
 N_PAGES = B * MAX_BLOCKS
 SSM_H, SSM_P, SSM_N = 80, 64, 128         # mamba2-2.7b: d_inner 5120 / 64
+SSM_ROWS = 16                             # the mamba2-2.7b.chat cell's rows
+SSM_D, SSM_VOCAB = 2560, 50288
+SSM_IN = 2 * 2 * SSM_D + 2 * SSM_N + SSM_H    # 10576: z, x, B, C, dt
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
 
@@ -88,9 +91,14 @@ def _compile(fn, one_chip, *shapes):
     (16 * C, D_MODEL, D_FF),
     (16 * C, D_FF, D_MODEL),
     (16, D_MODEL, VOCAB),
+    # mamba2-2.7b's chat cell: in-projection to z|x|B|C|dt, out-projection
+    (16, SSM_D, SSM_IN), (16, 2 * SSM_D, SSM_D),
+    (16 * C, SSM_D, SSM_IN), (16 * C, 2 * SSM_D, SSM_D),
+    (16, SSM_D, SSM_VOCAB),
 ], ids=["decode_proj", "lm_head", "prefill_mlp", "decode16_q", "decode16_kv",
         "decode16_up", "decode16_down", "prefill16_up", "prefill16_down",
-        "decode16_head"])
+        "decode16_head", "ssm_decode16_in", "ssm_decode16_out",
+        "ssm_prefill16_in", "ssm_prefill16_out", "ssm_decode16_head"])
 def test_gemm(one_chip, m, k, n):
     _compile(lambda a, b: gemm_pallas(a, b, interpret=False), one_chip,
              ((m, k), BF16), ((k, n), BF16))
@@ -156,14 +164,18 @@ def test_flash_attention_bwd(one_chip):
     )
 
 
-@pytest.mark.parametrize("s", [1, C], ids=["decode", "prefill_chunk"])
-def test_ssd_scan(one_chip, s):
-    _compile(
+@pytest.mark.parametrize("b,s", [(B, 1), (B, C), (SSM_ROWS, 1), (SSM_ROWS, C)],
+                         ids=["decode", "prefill_chunk", "decode_16rows",
+                              "prefill_chunk_16rows"])
+def test_ssd_scan(one_chip, b, s):
+    compiled = _compile(
         lambda x, dt, a, b_, c_, h: ssd_scan_pallas(
             x, dt, a, b_, c_, chunk=C, initial_state=h, interpret=False,
             tuning_op="ssd_prefill_chunk"),
         one_chip,
-        ((B, s, SSM_H, SSM_P), BF16), ((B, s, SSM_H), F32), ((SSM_H,), F32),
-        ((B, s, 1, SSM_N), BF16), ((B, s, 1, SSM_N), BF16),
-        ((B, SSM_H, SSM_P, SSM_N), F32),
+        ((b, s, SSM_H, SSM_P), BF16), ((b, s, SSM_H), F32), ((SSM_H,), F32),
+        ((b, s, 1, SSM_N), BF16), ((b, s, 1, SSM_N), BF16),
+        ((b, SSM_H, SSM_P, SSM_N), F32),
     )
+    name = "repro_ssd_decode" if s == 1 else "repro_ssd_prefill_chunk"
+    assert name in compiled.as_text()
